@@ -358,6 +358,9 @@ def run(fast: bool = False, device_counts=DEVICE_COUNTS, rounds: int | None = No
         "workload": "dse-bench-mnist-256-128-10",
         "strategy_quality": quality,
         "sweep": {
+            # workers run on forced host devices (JAX_PLATFORMS=cpu): a CPU
+            # measurement, even on a host with a chip
+            "platform": "cpu",
             "widths": list(widths),
             "device_counts": list(device_counts),
             "xla_flags": SINGLE_THREAD_FLAGS,
@@ -372,7 +375,7 @@ def run(fast: bool = False, device_counts=DEVICE_COUNTS, rounds: int | None = No
                 (
                     f"dse/sweep-w{w}-{n_dev}dev",
                     m["seconds_per_sweep"] * 1e6,
-                    f"cand_per_sec={m['candidates_per_sec']:.1f}",
+                    f"cand_per_sec={m['candidates_per_sec']:.1f};platform=cpu",
                 )
             )
 

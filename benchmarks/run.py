@@ -24,9 +24,12 @@ Prints ``name,us_per_call,derived`` CSV (one line per measurement).
   roofline  -- per (arch x shape) roofline terms from the dry-run records
 
 Usage: python -m benchmarks.run [--only table1,roofline] [--fast]
-       python -m benchmarks.run --compile-cache DIR [...]   # persistent jit cache
        python -m benchmarks.run --check-regression          # gate BENCH_*.json
                                                             # against baselines
+
+The persistent jit compilation cache is on: ``JAX_COMPILATION_CACHE_DIR``
+when set, else ``.jax_cache/`` at the checkout root
+(``repro.distributed.compat.enable_compilation_cache``).
 
 ``--check-regression`` compares the repo-root ``BENCH_*.json`` files (the
 committed perf trajectory, refreshed by a full ``benchmarks.run`` pass)
@@ -167,9 +170,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable jax's persistent compilation cache at DIR "
-                    "(repeat runs skip recompiles)")
     ap.add_argument("--check-regression", action="store_true",
                     help="compare repo-root BENCH_*.json against "
                     "benchmarks/baselines/ and exit nonzero on regression")
@@ -190,11 +190,9 @@ def main() -> None:
         print(f"no throughput regressions vs {baseline_dir}")
         return
 
-    if args.compile_cache:
-        from repro.distributed.compat import enable_compilation_cache
+    from repro.distributed.compat import enable_compilation_cache
 
-        if not enable_compilation_cache(args.compile_cache):
-            print("# persistent compilation cache unavailable on this jax", file=sys.stderr)
+    enable_compilation_cache()
 
     names = args.only.split(",") if args.only else MODULES
     print("name,us_per_call,derived")

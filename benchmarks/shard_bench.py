@@ -212,6 +212,9 @@ def run(fast: bool = False, device_counts=DEVICE_COUNTS, rounds: int | None = No
     top = best[device_counts[-1]]
     report = {
         "workload": "shard-bench-256x4-10",
+        # workers run on forced host devices (JAX_PLATFORMS=cpu): a CPU
+        # measurement, even on a host with a chip
+        "platform": "cpu",
         # in-process fan-out speedup relative to what the host can physically
         # deliver (1.0 = the sharded layer extracted every available core)
         "parallel_efficiency_vs_ceiling": (
@@ -246,21 +249,21 @@ def run(fast: bool = False, device_counts=DEVICE_COUNTS, rounds: int | None = No
             (
                 f"shard/eval-{n}dev",
                 b["eval"]["seconds_per_pass"] * 1e6,
-                f"samples_per_sec={b['eval']['samples_per_sec']:.1f}",
+                f"samples_per_sec={b['eval']['samples_per_sec']:.1f};platform=cpu",
             )
         )
         rows.append(
             (
                 f"shard/dse-{n}dev",
                 b["dse"]["seconds_per_sweep"] * 1e6,
-                f"cand_per_sec={b['dse']['candidates_per_sec']:.2f}",
+                f"cand_per_sec={b['dse']['candidates_per_sec']:.2f};platform=cpu",
             )
         )
         rows.append(
             (
                 f"shard/serve-{n}dev",
                 b["serve"]["seconds_per_pass"] * 1e6,
-                f"samples_per_sec={b['serve']['samples_per_sec']:.1f}",
+                f"samples_per_sec={b['serve']['samples_per_sec']:.1f};platform=cpu",
             )
         )
     for n, s in report["speedups_vs_1_device"].items():
@@ -269,7 +272,7 @@ def run(fast: bool = False, device_counts=DEVICE_COUNTS, rounds: int | None = No
                 f"shard/speedup-{n}dev",
                 0.0,
                 f"eval={s['eval_samples_per_sec_x']:.2f}x;dse={s['dse_candidates_per_sec_x']:.2f}x"
-                f";serve={s['serve_samples_per_sec_x']:.2f}x;ceiling_x2={ceiling:.2f}x",
+                f";serve={s['serve_samples_per_sec_x']:.2f}x;ceiling_x2={ceiling:.2f}x;platform=cpu",
             )
         )
     return rows

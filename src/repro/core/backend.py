@@ -96,7 +96,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.fixed_point import int_max
+from repro.core import lowering
 from repro.core.snn_layer import (
     IntLayerParams,
     ResetMode,
@@ -144,15 +144,19 @@ class SimRecord:
                     what layer l+1 integrates at its step t)
     input_events -- [T, batch] per-step ASPL counts into layer 0 (the input
                     raster's active channels; what core 0 integrates)
+    lowerings    -- per layer, the name of the lowering the backend ran it
+                    through (see ``repro.core.lowering``); ``None`` when the
+                    producer does not report it
 
-    Every backend populates all three fields, so any record can drive the
-    event-count-calibrated latency/energy model in ``repro.core.hw_model``
-    (see ``EventTraffic.from_record``).
+    Every backend populates the first three fields, so any record can drive
+    the event-count-calibrated latency/energy model in
+    ``repro.core.hw_model`` (see ``EventTraffic.from_record``).
     """
 
     spike_counts: jax.Array
     layer_spikes: list[jax.Array]
     input_events: jax.Array | None = None
+    lowerings: list[str] | None = None
 
     def predictions(self):
         return jnp.argmax(self.spike_counts, axis=-1)
@@ -184,6 +188,9 @@ class SimRecord:
         return float(total)
 
 
+_STEP_SCAN = "step-scan"  # the reference per-step walk (int_layer_step)
+
+
 def _run_step_major(net, params, spikes_in, init_fn, step_fn) -> SimRecord:
     """Step-major simulation: scan over time, walk the cores inside."""
     batch = spikes_in.shape[1]
@@ -204,7 +211,10 @@ def _run_step_major(net, params, spikes_in, init_fn, step_fn) -> SimRecord:
     layer_spikes = [emitted[:, i, :] for i in range(len(net.layers))]
     input_events = jnp.sum(spikes_in != 0, axis=-1)
     return SimRecord(
-        spike_counts=counts, layer_spikes=layer_spikes, input_events=input_events
+        spike_counts=counts,
+        layer_spikes=layer_spikes,
+        input_events=input_events,
+        lowerings=[_STEP_SCAN] * len(net.layers),
     )
 
 
@@ -270,10 +280,18 @@ class FusedBackend(InferenceBackend):
     ``use_pallas`` selects the Pallas kernels (default: only on TPU; the
     pure-jnp window oracle carries the identical numerics elsewhere, which
     keeps CPU/GPU runs fast -- interpret-mode Pallas is a debugging tool,
-    not a fast path).  ``interpret`` forces interpreter execution of the
-    kernels off-TPU; the parity suite uses ``use_pallas=True,
-    interpret=True`` to hold the *actual kernels* to the bit-exact contract
-    on CPU.
+    not a fast path).  The parity suite uses ``use_pallas=True`` to hold
+    the *actual kernels* to the bit-exact contract on CPU, where they run
+    in interpret mode (``interpret`` may only restate that; see
+    ``repro.core.lowering.interpret``).
+
+    Each eligible layer's integration takes ``lowering.mxu_feed(w_bits,
+    max_val)``: layer 0's ``max_val`` is measured from a concrete raster
+    (and picked on the device from a traced one), deeper layers integrate
+    {0,1} phase-B spikes.  The membrane scan is the ``lif_scan`` kernel,
+    except under traced weights (a ``vmap`` over candidates): the kernel
+    needs a static threshold, so those layers run the jnp oracle
+    ``lif_scan_ref`` -- named in the record's ``lowerings``.
     """
 
     name = "fused"
@@ -292,19 +310,23 @@ class FusedBackend(InferenceBackend):
 
     def _pallas_enabled(self) -> bool:
         if self.use_pallas is None:
-            return jax.default_backend() == "tpu"
+            return lowering.on_tpu()
         return self.use_pallas
 
-    def _interpret(self) -> bool:
-        if self.interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.interpret
+    def _fused_layer_window(self, cfg, p: IntLayerParams, raster, max_val):
+        """Whole-window spikes for one FF IF/LIF core via the kernel pair.
 
-    def _fused_layer_window(self, cfg, p: IntLayerParams, raster):
-        """Whole-window spikes for one FF IF/LIF core via the kernel pair."""
+        Returns ``(spikes, lowering name)``."""
         use_pallas = self._pallas_enabled()
+        interpret = lowering.interpret(self.interpret) if use_pallas else False
+        feed = lowering.mxu_feed(cfg.w_bits, max_val) if use_pallas else lowering.XLA_INT32
         currents = spike_integrate(
-            raster, p.w_ff, use_pallas=use_pallas, interpret=self._interpret()
+            raster,
+            p.w_ff,
+            w_bits=cfg.w_bits,
+            max_val=max_val,
+            use_pallas=use_pallas,
+            interpret=interpret,
         )
         code = cfg.beta_code()
         decay_k = 256 if code.bypass else code.k
@@ -317,37 +339,46 @@ class FusedBackend(InferenceBackend):
             jax.errors.ConcretizationTypeError,
         ):
             theta_q = None  # traced weights (e.g. under vmap): oracle only
-        T, B, N = currents.shape
-        bb, bn = min(self.block_b, B), min(self.block_n, N)
-        if theta_q is None or not use_pallas or B % bb or N % bn:
+        if theta_q is None or not use_pallas:
             theta = p.theta_q if theta_q is None else theta_q
             spikes, _ = lif_scan_ref(currents, theta, decay_k, cfg.u_bits, reset_to_zero)
-            return spikes
+            return spikes, f"{feed}+lif_scan_ref"
         spikes, _ = lif_scan(
             currents,
             theta_q=theta_q,
             decay_k=decay_k,
             u_bits=cfg.u_bits,
             reset_to_zero=reset_to_zero,
-            block_b=bb,
-            block_n=bn,
-            interpret=self._interpret(),
+            block_b=self.block_b,
+            block_n=self.block_n,
+            interpret=interpret,
         )
-        return spikes
+        return spikes, f"{feed}+lif_scan"
 
     def run_int(self, net, qparams, spikes_in) -> SimRecord:
         x = spikes_in.astype(jnp.int32)
         input_events = jnp.sum(x != 0, axis=-1)
-        emitted = []
+        # layer 0's largest input, where the host can see it; phase B emits {0,1}
+        max_val = (
+            None
+            if isinstance(x, jax.core.Tracer) or not self._pallas_enabled()
+            else int(jnp.max(jnp.abs(x)))
+        )
+        emitted, names = [], []
         for cfg, p in zip(net.layers, qparams):
             if fused_eligible(cfg):
-                x = self._fused_layer_window(cfg, p, x)
+                x, name = self._fused_layer_window(cfg, p, x, max_val)
             else:
-                x = int_layer_window(cfg, p, x)
+                x, name = int_layer_window(cfg, p, x), _STEP_SCAN
             emitted.append(jnp.sum(x, axis=-1))  # [T, batch]
+            names.append(name)
+            max_val = 1
         counts = jnp.sum(x, axis=0)
         return SimRecord(
-            spike_counts=counts, layer_spikes=emitted, input_events=input_events
+            spike_counts=counts,
+            layer_spikes=emitted,
+            input_events=input_events,
+            lowerings=names,
         )
 
     def run_float(self, net, params, spikes_in, spike_fn) -> SimRecord:
@@ -436,39 +467,30 @@ def _dense_layer_window(cfg, params: IntLayerParams, raster):
     """Density fallback: whole-window flat dense integration (one einsum
     over [T*B, n_in], the fused backend's shape) feeding the same step scan
     -- so even the fallback beats the step-major reference on wall-clock."""
-    currents = spike_integrate(raster, params.w_ff, use_pallas=False)
+    currents = spike_integrate(raster, params.w_ff)
     return int_layer_window_from_currents(cfg, params, currents)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("cfg", "budget", "f32_exact", "use_pallas", "interpret")
-)
-def _fixed_layer_window(
-    cfg, params: IntLayerParams, raster, budget, f32_exact, use_pallas, interpret
-):
-    """One layer's window through the fixed-capacity sparse accumulate.
+@functools.partial(jax.jit, static_argnames=("cfg", "budget", "how", "interpret"))
+def _fixed_layer_window(cfg, params: IntLayerParams, raster, budget, how, interpret):
+    """One layer's window for the pallas strategy.
 
-    ``budget`` is the static event budget (``None`` = the density fallback:
-    dense integration at the same lowering choices); ``f32_exact`` certifies
-    the f32 BLAS exactness bound for the off-TPU lowering (see
-    ``repro.kernels.sparse_accum.ops``).  Traceable end to end -- this is
-    the layer window the pallas strategy runs under an outer ``jax.jit`` /
+    ``how`` (static) is the layer's lowering: ``lowering.PALLAS_SPARSE``
+    scatters the fixed-capacity event list at ``budget`` through the Pallas
+    kernel; ``lowering.F32`` / ``lowering.XLA_INT32`` integrate densely
+    (the caller certified ``F32`` against the budget, or against ``n_in``
+    on the density fallback).  Traceable end to end -- this is the layer
+    window the pallas strategy runs under an outer ``jax.jit`` /
     ``shard_map``.
     """
-    if budget is None:
-        if f32_exact:
-            currents = _ff_currents_f32_exact(raster, params.w_ff)
-        else:
-            currents = spike_integrate(raster, params.w_ff, use_pallas=False)
-    else:
+    if how == lowering.PALLAS_SPARSE:
         currents = sparse_accum_currents(
-            raster,
-            params.w_ff,
-            budget,
-            f32_exact=f32_exact,
-            use_pallas=use_pallas,
-            interpret=interpret,
+            raster, params.w_ff, budget, use_pallas=True, interpret=interpret
         )
+    elif how == lowering.F32:
+        currents = lowering.f32_currents(raster, params.w_ff)
+    else:
+        currents = spike_integrate(raster, params.w_ff)
     return int_layer_window_from_currents(cfg, params, currents)
 
 
@@ -523,9 +545,9 @@ class EventBackend(InferenceBackend):
     from the concrete rasters).  ``input_max_val`` (static int, default 1 =
     binary spike rasters, the repo-wide raster contract) bounds input values
     for the same traced path: together with the budget it certifies the
-    exact-f32 lowering (``input_max_val * budget * int_max(w_bits) <
-    2**24``); graded rasters above the declared bound fall back to the
-    exact int einsum.  Deeper layers need no declaration -- phase-B spikes
+    exact-f32 lowering off-TPU (``lowering.f32_exact(w_bits,
+    input_max_val, budget)``); where it cannot certify, the exact int
+    einsum runs instead.  Deeper layers need no declaration -- phase-B spikes
     are {0,1}, which certifies every supported core size.
 
     Bit-exact to ``reference`` on every neuron model x topology x reset mode
@@ -606,7 +628,7 @@ class EventBackend(InferenceBackend):
             return self.strategy
         if traced:
             return "pallas"
-        if jax.default_backend() == "tpu" or _scipy_sparse is None:
+        if lowering.on_tpu() or _scipy_sparse is None:
             return "gather"
         return "csr"
 
@@ -641,10 +663,18 @@ class EventBackend(InferenceBackend):
         k = max(1, int(2 * admission_threshold * n_in))
         return min(n_in, _round_capacity(k, self.capacity_multiple))
 
-    def _f32_certified(self, cfg, budget: int | None, max_val: int) -> bool:
-        """True when the budget bound certifies the exact-f32 lowering."""
+    def _fixed_lowering(self, cfg, budget: int | None, max_val: int) -> str:
+        """The pallas strategy's lowering for one layer: the sparse kernel
+        where it runs (on TPU, or forced by ``use_pallas``), else the f32
+        matmul where ``lowering.f32_exact`` certifies it against the budget
+        (against ``n_in`` on the density fallback), else the int32 dot."""
+        kernel = lowering.on_tpu() if self.use_pallas is None else self.use_pallas
+        if budget is not None and kernel:
+            return lowering.PALLAS_SPARSE
         rows = cfg.n_in if budget is None else min(budget, cfg.n_in)
-        return int_max(cfg.w_bits) * rows * max_val < 2**24
+        if lowering.f32_exact(cfg.w_bits, max_val, rows):
+            return lowering.F32
+        return lowering.XLA_INT32
 
     def run_int(self, net, qparams, spikes_in) -> SimRecord:
         x = jnp.asarray(spikes_in)
@@ -662,18 +692,23 @@ class EventBackend(InferenceBackend):
         if strategy == "pallas" or traced:
             return self._run_int_fixed(net, qparams, x, traced)
         input_events = jnp.sum(x != 0, axis=-1)
-        emitted = []
+        emitted, names = [], []
         for cfg, p in zip(net.layers, qparams):
             k_max = int(jnp.max(jnp.sum(x != 0, axis=-1)))  # concrete: host value
             k = self._budget(k_max, cfg)
             if k > self.dense_threshold * cfg.n_in:
                 x = _dense_layer_window(cfg, p, x)
+                names.append(lowering.XLA_INT32)
             else:
                 x = _event_layer_window(cfg, p, x, k)
+                names.append(f"gather@{k}")
             emitted.append(jnp.sum(x, axis=-1))  # [T, batch]
         counts = jnp.sum(x, axis=0)
         return SimRecord(
-            spike_counts=counts, layer_spikes=emitted, input_events=input_events
+            spike_counts=counts,
+            layer_spikes=emitted,
+            input_events=input_events,
+            lowerings=names,
         )
 
     def _run_int_fixed(self, net, qparams, x, traced: bool) -> SimRecord:
@@ -688,7 +723,7 @@ class EventBackend(InferenceBackend):
         composes with an outer ``jax.jit`` / ``shard_map``.
         """
         input_events = jnp.sum(x != 0, axis=-1)
-        emitted = []
+        emitted, names = [], []
         max_val = self.input_max_val if traced else max(1, int(jnp.max(x)))
         for i, (cfg, p) in enumerate(zip(net.layers, qparams)):
             if traced:
@@ -698,15 +733,19 @@ class EventBackend(InferenceBackend):
                 budget = self.static_budget(cfg.n_in, k_max=k_max)
             if budget > self.dense_threshold * cfg.n_in:
                 budget = None  # density fallback: dense lowering, same numerics
-            f32_ok = self._f32_certified(cfg, budget, max_val)
-            x = _fixed_layer_window(
-                cfg, p, x, budget, f32_ok, self.use_pallas, self.interpret
-            )
+            how = self._fixed_lowering(cfg, budget, max_val)
+            sparse = how == lowering.PALLAS_SPARSE
+            interpret = lowering.interpret(self.interpret) if sparse else False
+            x = _fixed_layer_window(cfg, p, x, budget, how, interpret)
             emitted.append(jnp.sum(x, axis=-1))  # [T, batch]
+            names.append(f"{how}@{budget}" if sparse else how)
             max_val = 1  # phase B emits {0,1}
         counts = jnp.sum(x, axis=0)
         return SimRecord(
-            spike_counts=counts, layer_spikes=emitted, input_events=input_events
+            spike_counts=counts,
+            layer_spikes=emitted,
+            input_events=input_events,
+            lowerings=names,
         )
 
     def jit_surrogate(self, net, spikes_in) -> "EventBackend | None":
@@ -748,14 +787,16 @@ class EventBackend(InferenceBackend):
         active = x != 0  # [T, batch, n_in] byte mask, reused by the CSR build
         counts = active.sum(axis=-1)  # [T, batch]
         input_events = counts
-        emitted = []
+        emitted, names = [], []
         for cfg, p in zip(net.layers, qparams):
             k = self._budget(int(counts.max(initial=0)), cfg)
             if k > self.dense_threshold * cfg.n_in:
                 x = np.asarray(_dense_layer_window(cfg, p, jnp.asarray(x)))
                 active = x != 0
                 counts = active.sum(axis=-1)
+                names.append(lowering.XLA_INT32)
             else:
+                names.append("csr")
                 currents = _csr_currents(x, np.asarray(p.w_ff), active, counts)
                 x = np.asarray(_phase_b_window(cfg, p, jnp.asarray(currents)))
                 # phase B emits {0,1}: the spike raster is its own mask and
@@ -767,6 +808,7 @@ class EventBackend(InferenceBackend):
             spike_counts=jnp.asarray(x.sum(axis=0)),
             layer_spikes=[jnp.asarray(e) for e in emitted],
             input_events=jnp.asarray(input_events),
+            lowerings=names,
         )
 
     def run_float(self, net, params, spikes_in, spike_fn) -> SimRecord:
@@ -959,22 +1001,6 @@ def lane_state_put(states, lane: int, carry) -> list:
     )
 
 
-def _ff_currents_f32_exact(x, w_ff):
-    """Feed-forward chunk integration through the f32 BLAS path, bit-exactly.
-
-    Every partial sum is an integer with magnitude <= max_spike * n_in *
-    int_max(w_bits); the *caller* guarantees that bound stays below 2**24
-    (f32's exact-integer range), so products, partial sums in any
-    association order, and the final cast back to int32 are all exact.
-    On CPU this routes the hot matmul through BLAS instead of XLA's naive
-    integer loops.
-    """
-    T, B, n_in = x.shape
-    flat = x.reshape(T * B, n_in).astype(jnp.float32)
-    cur = flat @ w_ff.astype(jnp.float32)
-    return cur.astype(jnp.int32).reshape(T, B, -1)
-
-
 @functools.partial(jax.jit, static_argnames=("net", "ff_mode", "event_budget"))
 def batched_lane_window(
     net,
@@ -1033,19 +1059,19 @@ def batched_lane_window(
 
     ``ff_mode`` (static) selects how the feed-forward matmul is computed:
     ``"int32"`` (exact by construction) or ``"f32_exact"``, which routes it
-    through the f32 BLAS path -- still bit-exact *provided the caller has
-    checked* ``max_spike_value * n_in * int_max(w_bits) < 2**24`` for every
-    layer (the serving engine checks this per network and per request;
-    deeper layers always qualify because phase-B spikes are {0,1}).
+    through ``lowering.f32_currents`` -- still bit-exact *provided the
+    caller has checked* ``lowering.f32_exact(w_bits, max_spike_value,
+    n_in)`` for every layer (the serving engine checks this per network and
+    per request; deeper layers integrate {0,1} phase-B spikes).
 
     ``event_budget`` (static) routes *layer 0* through the fixed-capacity
     sparse event path (``repro.kernels.sparse_accum``) at that budget: the
     Pallas AER scatter on TPU, the budget-certified exact-f32 lowering
     elsewhere.  The caller guarantees the capacity contract -- every active
-    lane's chunk rows carry at most ``event_budget`` active channels with
-    ``max_spike_value * event_budget * int_max(l0.w_bits) < 2**24`` (the
-    serving engine enforces both at admission, see the ``"event-pallas"``
-    route).  Deeper layers follow ``ff_mode`` as usual.
+    lane's chunk rows carry at most ``event_budget`` active channels -- and,
+    off-TPU, ``lowering.f32_exact(l0.w_bits, max_spike_value,
+    event_budget)`` (the serving engine enforces both at admission, see the
+    ``"event-pallas"`` route).  Deeper layers follow ``ff_mode`` as usual.
     """
     states = jax.tree.map(
         lambda a: jnp.where(reset_mask[:, None], jnp.zeros_like(a), a), states
@@ -1060,9 +1086,9 @@ def batched_lane_window(
         if li == 0 and event_budget is not None:
             currents = sparse_accum_currents(x, p.w_ff, min(event_budget, cfg.n_in))
         elif ff_mode == "f32_exact":
-            currents = _ff_currents_f32_exact(x, p.w_ff)
+            currents = lowering.f32_currents(x, p.w_ff)
         else:
-            currents = spike_integrate(x, p.w_ff, use_pallas=False)
+            currents = spike_integrate(x, p.w_ff)
         st, x = int_layer_window_carry(cfg, p, st, currents, live=live)
         new_states.append(st)
         emitted.append(jnp.sum(x, axis=-1))  # [k, n_lanes]

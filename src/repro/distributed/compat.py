@@ -1,28 +1,18 @@
-"""Version-compatibility shims for jax distributed APIs.
+"""Process-level jax plumbing shared by the distributed substrate.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the ``jax``
-top level, and its replication-checking kwarg was renamed along the way
-(``check_rep`` -> ``check_vma``, when varying-axis tracking landed).
-``jax.lax.pcast`` only exists on jax versions with varying-axis tracking.
-Everything in the distributed substrate goes through this module so the
-rest of the code is written against the *new* API surface and runs on
-both.
+``shard_map`` / ``pcast_varying`` are the two mesh primitives the
+substrate calls; the persistent compilation cache and multi-process
+initialisation are set up here once per entry point.
 """
 
 from __future__ import annotations
 
-import inspect
 import os
+import pathlib
 import warnings
 
 import jax
-
-try:  # newer jax: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = set(inspect.signature(_shard_map).parameters)
+from jax.experimental.compilation_cache import compilation_cache as _cc
 
 __all__ = [
     "shard_map",
@@ -35,51 +25,38 @@ __all__ = [
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg name normalised.
+    """``jax.shard_map`` (``check_vma`` verifies the claimed varying axes)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma, **kwargs
+    )
 
-    Callers pass ``check_vma`` (the current name); on older jax it is
-    forwarded as ``check_rep`` (same meaning: verify the claimed
-    replication/varying axes of outputs).
+
+#: The checkout root (``src/repro/distributed/compat.py`` -> root).
+_CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compilation_cache() -> pathlib.Path:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places it when set (so a host can keep
+    one cache across checkouts); otherwise it is ``.jax_cache/`` at the
+    checkout root -- a fixed path, since the path is part of each entry's
+    key.  Every entry point calls this once before it compiles, so a
+    restarted process skips the compiles of unchanged programs.  The
+    persistence thresholds drop to zero: the serving chunk programs are
+    small and compile fast, exactly the entries the defaults would decline
+    to keep.
     """
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = check_vma
-    else:
-        kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def enable_compilation_cache(cache_dir) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` (opt-in).
-
-    Repeated bench/serve runs then skip recompiles of unchanged programs
-    across *processes* -- the in-process jit cache only lives as long as the
-    interpreter.  The threshold knobs are dropped to zero where they exist
-    (our chunk programs are small and compile fast, exactly the entries the
-    defaults would decline to persist).  Returns False on jax versions
-    without the cache config; callers treat that as "not enabled".
-    """
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except AttributeError:  # pragma: no cover - ancient jax
-        return False
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except AttributeError:  # knob not in this jax: keep its default
-            pass
-    try:
-        # the cache backend latches "absent" on the first compile of the
-        # process; a process that already compiled something must reset it
-        # for the new directory to take effect
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except (ImportError, AttributeError):  # pragma: no cover - internal API
-        pass
-    return True
+    cache_dir = pathlib.Path(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_ROOT / ".jax_cache"
+    )
+    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # the cache backend latches its directory on the first compile of the
+    # process; reset it so a process that already compiled picks this one up
+    _cc.reset_cache()
+    return cache_dir
 
 
 def process_count() -> int:
@@ -144,12 +121,5 @@ def maybe_init_distributed(
 
 
 def pcast_varying(x, axis_name: str):
-    """Mark ``x`` as varying over ``axis_name`` where the tracker exists.
-
-    On jax versions without varying-axis tracking this is the identity --
-    those versions don't type-check loop carries against manual-axis
-    variance, so no cast is needed.
-    """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    return x
+    """Mark ``x`` as varying over ``axis_name`` (shard_map loop carries)."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")

@@ -72,10 +72,17 @@ def lif_scan(
     block_n: int = 128,
     interpret: bool = False,
 ):
-    """Fused LIF window scan. Returns (spikes [T, B, N], final_u [B, N])."""
+    """Fused LIF window scan. Returns (spikes [T, B, N], final_u [B, N]).
+
+    A batch that does not tile by ``block_b`` is zero-padded and sliced
+    back (lanes never interact); so is a layer wider than one ``block_n``
+    tile that does not tile by it.  A layer narrower than ``block_n`` is
+    one full-width tile.
+    """
     T, B, N = currents.shape
-    if B % block_b or N % block_n:
-        raise ValueError(f"B={B} and N={N} must tile by ({block_b}, {block_n})")
+    block_n = N if N <= block_n else block_n
+    Bp, Np = -(-B // block_b) * block_b, -(-N // block_n) * block_n
+    currents = jnp.pad(currents, ((0, 0), (0, Bp - B), (0, Np - N)))
 
     kernel = functools.partial(
         _kernel,
@@ -85,9 +92,9 @@ def lif_scan(
         reset_to_zero=reset_to_zero,
         t_steps=T,
     )
-    return pl.pallas_call(
+    spikes, u = pl.pallas_call(
         kernel,
-        grid=(B // block_b, N // block_n),
+        grid=(Bp // block_b, Np // block_n),
         in_specs=[
             pl.BlockSpec((T, block_b, block_n), lambda i, j: (0, i, j)),
         ],
@@ -96,8 +103,9 @@ def lif_scan(
             pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T, B, N), jnp.int32),
-            jax.ShapeDtypeStruct((B, N), jnp.int32),
+            jax.ShapeDtypeStruct((T, Bp, Np), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, Np), jnp.int32),
         ],
         interpret=interpret,
     )(currents)
+    return spikes[:, :B, :N], u[:B, :N]

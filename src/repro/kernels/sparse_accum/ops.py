@@ -3,20 +3,20 @@
 ``fixed_capacity_events`` is the jit-compatible AER encoder: it compacts a
 spike raster into the static-budget event list the kernel consumes.
 ``sparse_accum_currents`` is the window-level integration op the event
-backend and the serving lane window call; like ``FusedBackend`` it treats
-the Pallas kernel as the TPU fast path and carries the identical numerics
-through XLA elsewhere (interpret-mode Pallas is a debugging tool, not a
-fast path -- the parity suite in ``tests/test_sparse_accum.py`` holds the
-actual kernel to the bit-exact contract on CPU via ``interpret=True``).
+backend and the serving lane window call.  On TPU it always runs the Pallas
+kernel; elsewhere the identical numerics run through XLA (interpret-mode
+Pallas is a debugging tool, not a fast path -- the parity suite in
+``tests/test_sparse_accum.py`` holds the actual kernel to the bit-exact
+contract on CPU via ``interpret=True``).
 
 Off-TPU the lowering is chosen by an exactness certificate the *budget*
-provides: every output row accumulates at most ``budget`` events, so when
-``budget * max_value * int_max(w_bits) < 2**24`` the f32 BLAS matmul is
-bit-exact (every product and partial sum is an exactly-representable
-integer) and 4-5x faster than XLA's integer loops on CPU -- this is what
-makes the jitted event strategy *faster* than the dense int path even
-though XLA:CPU's gather/scatter lowerings lose to their own dense matmul.
-When the certificate fails, the exact int einsum carries the numerics.
+provides: every output row accumulates at most ``budget`` events, so where
+``repro.core.lowering.f32_exact(w_bits, max_value, budget)`` holds the f32
+BLAS matmul is bit-exact and 4-5x faster than XLA's integer loops on CPU --
+this is what makes the jitted event strategy *faster* than the dense int
+path even though XLA:CPU's gather/scatter lowerings lose to their own dense
+matmul.  When the certificate fails, the exact int einsum carries the
+numerics.
 
 Budget semantics: the budget is a capacity contract -- callers size it at
 or above the measured max per-row active-channel count (see
@@ -33,11 +33,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import lowering
 from repro.kernels.sparse_accum.sparse_accum import sparse_accum
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def fixed_capacity_events(raster, budget: int):
@@ -65,33 +62,27 @@ def sparse_accum_currents(
     f32_exact: bool = True,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-    be: int = 256,
-    bn: int = 128,
 ):
     """Window FF currents [T, B, N] via the fixed-capacity event formulation.
 
     On TPU (or with ``use_pallas=True``) the raster is AER-encoded at the
     static ``budget`` and scattered through the Pallas kernel.  Elsewhere
     the identical int32 result comes from the f32 BLAS matmul when the
-    caller certifies the budget bound (``f32_exact=True`` asserts
-    ``budget * max_value * int_max(w_bits) < 2**24``; see module docstring)
-    and from the exact int einsum otherwise.  All paths share the dense
-    matmul's wraparound semantics for any sufficient budget.
+    caller certifies it (``f32_exact=True`` asserts
+    ``lowering.f32_exact(w_bits, max_value, budget)``) and from the exact
+    int einsum otherwise.  All paths share the dense matmul's wraparound
+    semantics for any sufficient budget.
     """
     T, B, n_in = raster.shape
-    N = w_q.shape[1]
     budget = min(budget, n_in)
     flat = raster.astype(jnp.int32).reshape(T * B, n_in)
-    E = T * B
     if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
-    if use_pallas and not (E % min(be, E) or N % min(bn, N)):
+        use_pallas = lowering.on_tpu()
+    if use_pallas:
         vals, idx = fixed_capacity_events(flat, budget)
-        out = sparse_accum(vals, idx, w_q, be=be, bn=bn, interpret=interpret)
+        out = sparse_accum(vals, idx, w_q, interpret=lowering.interpret(interpret))
     elif f32_exact:
-        out = (flat.astype(jnp.float32) @ w_q.astype(jnp.float32)).astype(jnp.int32)
+        out = lowering.f32_currents(flat, w_q)
     else:
         out = jnp.einsum("ek,kn->en", flat, w_q.astype(jnp.int32))
-    return out.reshape(T, B, N)
+    return out.reshape(T, B, -1)

@@ -12,7 +12,10 @@ queued up front); ``--density`` switches the workload from mnist-like
 rasters to Bernoulli spike noise at the given density, which is how to
 exercise the event backend's sparse admission route.  Prints throughput,
 latency percentiles, per-route counts, the scheduler's QoS counters, and
-the modeled hardware operating point of a few sample requests.
+the modeled hardware operating point of a few sample requests.  Compiled
+programs persist in JAX's compilation cache (``JAX_COMPILATION_CACHE_DIR``
+when set, else ``.jax_cache/`` at the checkout root), so a restarted server
+skips its warmup compiles.
 
 QoS knobs drive the front-line scheduler: ``--critical-frac`` /
 ``--standard-frac`` split the workload across priority classes,
@@ -55,6 +58,7 @@ import numpy as np
 from repro.core.network import NetworkConfig, init_float_params, quantize_params
 from repro.core.snn_layer import LayerConfig, NeuronModel
 from repro.data.snn_datasets import mnist_like
+from repro.distributed.compat import enable_compilation_cache
 from repro.serve.http import SNNHttpServer
 from repro.serve.journal import Journal, recover
 from repro.serve.scheduler import PrecisionTier, Priority, SchedPolicy
@@ -129,8 +133,7 @@ def _run_streaming(args, net, engine, apply_recovery=None) -> None:
     )
     density = args.density if args.density is not None else 0.2
     # warmup resets pool + metrics: run it before any session bookkeeping
-    engine.warmup(max(2 * args.stream_chunk, 8),
-                  compilation_cache_dir=args.compile_cache)
+    engine.warmup(max(2 * args.stream_chunk, 8))
     if apply_recovery is not None:
         apply_recovery(manager)
     remaining = {}
@@ -216,9 +219,6 @@ def main():
     ap.add_argument("--data-parallel", type=int, default=None,
                     help="shard the lane pool across this many devices "
                     "(clamped to what exists; must divide --max-batch)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jax compilation cache directory "
-                    "(restarted engines skip the warmup compiles)")
     ap.add_argument("--critical-frac", type=float, default=0.0,
                     help="fraction of requests submitted as CRITICAL")
     ap.add_argument("--standard-frac", type=float, default=1.0,
@@ -259,6 +259,7 @@ def main():
                     help="disable the write-ahead journal entirely")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     net = _build_net(args.hidden, args.T)
     params = init_float_params(jax.random.PRNGKey(args.seed), net)
@@ -315,7 +316,7 @@ def main():
     _install_drain_handlers(engine)
 
     if args.http is not None:
-        engine.warmup(args.T, compilation_cache_dir=args.compile_cache)
+        engine.warmup(args.T)
 
         async def _serve_http():
             async_server = AsyncSNNServer(engine)
@@ -421,7 +422,7 @@ def main():
 
     # precompile the chunk programs + the event route so the report
     # reflects steady-state service, not jit compilation
-    engine.warmup(args.T, compilation_cache_dir=args.compile_cache)
+    engine.warmup(args.T)
     rec_mgr = _apply_recovery()
 
     try:

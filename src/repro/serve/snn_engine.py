@@ -78,13 +78,14 @@ import asyncio
 import dataclasses
 import functools
 import time
+import warnings
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import hw_model
+from repro.core import hw_model, lowering
 from repro.core import shard as shard_lib
 from repro.core.fixed_point import int_max, int_min
 from repro.core.backend import (
@@ -98,7 +99,6 @@ from repro.core.backend import (
     run_int_batched,
 )
 from repro.core.network import NetworkConfig, run_int
-from repro.distributed.compat import enable_compilation_cache
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import PrecisionTier, Priority, SchedPolicy, Scheduler
 
@@ -410,8 +410,9 @@ class SNNServeEngine:
     ``i``, one jitted tick advances every shard, and admission stays a
     global host-side decision (a request lands on whichever lane is free;
     the lane index *is* the placement).  ``max_batch`` must divide evenly.
-    Requests for more devices than exist clamp down -- on a single-device
-    host this degrades to the unsharded engine, bit-exactly.  Routing and
+    Requests for more devices than exist clamp down with a
+    ``RuntimeWarning`` -- on a single-device host this degrades to the
+    unsharded engine, bit-exactly.  Routing and
     numerics are unchanged: lanes never interact, so the sharded pool's
     trajectories are identical to the serial pool's (asserted by the serve
     parity tests).
@@ -498,6 +499,13 @@ class SNNServeEngine:
             n = min(data_parallel, n_avail)
             while max_batch % n:
                 n -= 1
+            if n < data_parallel:
+                warnings.warn(
+                    f"data_parallel={data_parallel} clamped to {n}: {n_avail} "
+                    f"device(s) visible, max_batch={max_batch}",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
             if n > 1:
                 self._dmesh = shard_lib.make_mesh(n)
         self.data_parallel = self._dmesh.n_shards if self._dmesh is not None else 1
@@ -509,30 +517,34 @@ class SNNServeEngine:
         self.n_served = 0
         self._admit_seq = 0  # first-admission counter (FIFO-order evidence)
         self._idle_rounds = 0  # consecutive no-progress polls (liveness guard)
-        # Largest layer-0 input spike value for which the f32 BLAS
-        # feed-forward path stays exact (see _ff_currents_f32_exact); deeper
-        # layers always integrate {0,1} phase-B spikes, so they only need
-        # the static per-layer bound to hold.
-        bound = 2**24 - 1
-        self._deep_f32_ok = all(int_max(c.w_bits) * c.n_in < bound for c in net.layers[1:])
+        # Largest layer-0 input spike value for which the f32 feed-forward
+        # lowering stays exact (``lowering.f32_exact``); deeper layers always
+        # integrate {0,1} phase-B spikes, so they only need it at value 1.
+        l0 = net.layers[0]
+        self._deep_f32_ok = all(
+            lowering.f32_exact(c.w_bits, 1, c.n_in) for c in net.layers[1:]
+        )
         self._f32_input_max: int = 0
         if self._deep_f32_ok:
-            l0 = net.layers[0]
-            self._f32_input_max = bound // (int_max(l0.w_bits) * l0.n_in)
+            self._f32_input_max = lowering.f32_max_input(l0.w_bits, l0.n_in)
         # The jitted sparse lane route: with an event backend resolving to the
         # pallas strategy, sparse requests stay in the lane pool and the
-        # chunk advance takes the fixed-capacity path for layer 0.  The
-        # budget doubles as the f32 exactness certificate: a request admits
-        # to the sparse route only when its max per-step active-channel
-        # count fits the budget AND its values stay under _sparse_val_max.
+        # chunk advance takes the fixed-capacity path for layer 0.  A request
+        # admits to the sparse route only when its max per-step
+        # active-channel count fits the budget AND its values stay under
+        # _sparse_val_max: on TPU the Pallas kernel accumulates int32 for any
+        # value; elsewhere the budget certifies the f32 lowering.
         self._event_budget: int | None = None
         self._sparse_val_max: int = 0
         if self.event_backend is not None and self.event_backend.resolved_strategy() == "pallas":
-            l0 = net.layers[0]
             self._event_budget = self.event_backend.serve_budget(
                 l0.n_in, sparse_admission_threshold
             )
-            self._sparse_val_max = bound // (int_max(l0.w_bits) * self._event_budget)
+            self._sparse_val_max = (
+                np.iinfo(np.int32).max
+                if lowering.on_tpu()
+                else lowering.f32_max_input(l0.w_bits, self._event_budget)
+            )
 
     # -- introspection ------------------------------------------------------
     @property
@@ -561,6 +573,19 @@ class SNNServeEngine:
     @property
     def in_flight(self) -> bool:
         return bool(self.sched) or self.active_lanes > 0
+
+    def route_lowerings(self) -> dict[str, list[str]]:
+        """Per lane-pool program, the lowering each layer's feed-forward
+        takes for binary traffic (``repro.core.lowering`` names; inputs
+        above ``_f32_input_max`` switch the dense program to the int32 dot).
+        ``"event-pallas"`` appears when the sparse lane program exists."""
+        deep = lowering.F32 if self._deep_f32_ok else lowering.XLA_INT32
+        l0 = lowering.F32 if self._f32_input_max >= 1 else lowering.XLA_INT32
+        routes = {"lanes": [l0] + [deep] * (len(self.net.layers) - 1)}
+        if self._event_budget is not None:
+            sparse = lowering.PALLAS_SPARSE if lowering.on_tpu() else lowering.F32
+            routes["event-pallas"] = [f"{sparse}@{self._event_budget}"] + routes["lanes"][1:]
+        return routes
 
     # -- admission ----------------------------------------------------------
     def submit(self, req: SNNRequest) -> None:
@@ -1073,12 +1098,7 @@ class SNNServeEngine:
         self.metrics.inc("quarantine_restarts")
         return req
 
-    def warmup(
-        self,
-        n_steps: int | None = None,
-        include_int32: bool = False,
-        compilation_cache_dir: str | None = None,
-    ) -> None:
+    def warmup(self, n_steps: int | None = None, include_int32: bool = False) -> None:
         """Precompile the chunk programs a typical workload will hit.
 
         Compiles the power-of-two lane-window programs up to the chunk that
@@ -1099,18 +1119,16 @@ class SNNServeEngine:
         or large-valued inputs, so the int32 fallback programs (both the
         int32 input dtype and ``ff_mode="int32"``) compile up front too.
 
-        ``compilation_cache_dir`` opts into jax's *persistent* compilation
-        cache before compiling, so an engine restarted with the same
-        network skips these compiles entirely on the next process
-        (``repro.distributed.compat.enable_compilation_cache``).
+        An entry point that called
+        ``repro.distributed.compat.enable_compilation_cache`` keeps these
+        compiles in JAX's persistent cache, so an engine restarted with the
+        same network skips them in the next process.
 
         Warmup traffic leaves no trace: ``n_served`` and the metrics layer
         are reset on the way out.
         """
         if self.in_flight:
             raise RuntimeError("warmup() requires an idle engine")
-        if compilation_cache_dir is not None:
-            enable_compilation_cache(compilation_cache_dir)
         T = self.net.n_steps if n_steps is None else n_steps
         cap = self._chunk_cap()
         combos = [(np.uint8, "f32_exact" if self._f32_input_max >= 1 else "int32", None)]

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import backend as backend_lib
-from repro.core import coeff_gen
+from repro.core import coeff_gen, lowering
 from repro.core.backend import EventBackend, FusedBackend, get_backend
 from repro.core.network import (
     NetworkConfig,
@@ -82,6 +82,30 @@ def test_fused_bit_exact_ff(neuron, reset, shape):
         net, qparams, spikes, backend=FusedBackend(use_pallas=True, interpret=True)
     )
     _assert_records_equal(ref, fused)
+
+
+@pytest.mark.parametrize(
+    "w_bits,max_val",
+    [(6, 1), (6, 200), (12, 1)],
+    ids=["int8_binary", "int32_graded", "int32_wide_weights"],
+)
+def test_fused_kernel_lowering_eager_and_jitted(w_bits, max_val):
+    """The kernel path's MXU feed follows ``lowering.mxu_feed``: eager runs
+    pick it from the measured maximum (named in the record), traced runs
+    pick on the device; every choice is bit-exact."""
+    net = _make_net(19, 11, 5, 6, NeuronModel.LIF, ResetMode.SUBTRACT, w_bits=w_bits)
+    qparams = _quantized(net)
+    spikes = _spikes(net, 6, 3) * max_val
+    fused = FusedBackend(use_pallas=True, interpret=True)
+    ref = run_int(net, qparams, spikes)
+    eager = run_int(net, qparams, spikes, backend=fused)
+    _assert_records_equal(ref, eager)
+    assert eager.lowerings == [
+        f"{lowering.mxu_feed(w_bits, max_val)}+lif_scan",
+        f"{lowering.mxu_feed(w_bits, 1)}+lif_scan",
+    ]
+    jitted = jax.jit(lambda s: run_int(net, qparams, s, backend=fused).spike_counts)
+    np.testing.assert_array_equal(np.asarray(jitted(spikes)), np.asarray(ref.spike_counts))
 
 
 @pytest.mark.parametrize("leak_bits", [2, 5, 8])

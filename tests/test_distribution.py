@@ -127,17 +127,32 @@ def test_elastic_mesh_roundtrip_with_checkpointer(tmp_path):
     assert restored["w"].sharding.spec == P("data", "model")
 
 
-def test_enable_compilation_cache_populates(tmp_path):
-    """Opt-in persistent jit cache: compiles land on disk, then restore off."""
-    from repro.distributed.compat import enable_compilation_cache
+@pytest.mark.parametrize("from_env", [True, False], ids=["env_dir", "checkout_dir"])
+def test_enable_compilation_cache_populates(tmp_path, monkeypatch, from_env):
+    """The persistent jit cache goes where ``JAX_COMPILATION_CACHE_DIR``
+    says, else to ``.jax_cache/`` at the checkout root; compiles land there."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    assert enable_compilation_cache(tmp_path)
+    from repro.distributed import compat
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = tmp_path
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        # keep the test's entries out of the real checkout
+        monkeypatch.setattr(compat, "_CHECKOUT_ROOT", tmp_path)
+        want = tmp_path / ".jax_cache"
     try:
-        fn = jax.jit(lambda x: x * 3 + 1)
-        np.testing.assert_allclose(np.asarray(fn(jnp.arange(64.0))), np.arange(64.0) * 3 + 1)
-        assert list(tmp_path.iterdir()), "no cache entries written"
+        got = compat.enable_compilation_cache()
+        assert got == want
+        assert jax.config.jax_compilation_cache_dir == str(want)
+        fn = jax.jit(lambda x: x * 3 + 7)
+        np.testing.assert_allclose(np.asarray(fn(jnp.arange(64.0))), np.arange(64.0) * 3 + 7)
+        assert list(want.iterdir()), "no cache entries written"
     finally:
         jax.config.update("jax_compilation_cache_dir", None)
+        cc.reset_cache()
 
 
 def test_ring_allgather_matmul_matches_dense():

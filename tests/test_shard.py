@@ -309,11 +309,16 @@ def test_sharded_serve_lanes_bit_exact():
 
     net = _make_net(T=8)
     _, qparams = _quantized(net)
-    # data_parallel over-asks clamp to the largest usable shard count
-    eng = SNNServeEngine(net, qparams, max_batch=8, data_parallel=8)
+    # data_parallel over-asks clamp, with a warning, to the largest usable
+    # shard count
     expected = min(8, N_DEV)
     while 8 % expected:
         expected -= 1
+    if expected < 8:
+        with pytest.warns(RuntimeWarning, match="clamped"):
+            eng = SNNServeEngine(net, qparams, max_batch=8, data_parallel=8)
+    else:
+        eng = SNNServeEngine(net, qparams, max_batch=8, data_parallel=8)
     assert eng.data_parallel == expected
     rng = np.random.default_rng(0)
     reqs = [
@@ -339,7 +344,8 @@ def test_sharded_serve_rejects_indivisible_pool():
         with pytest.raises(ValueError, match="divide max_batch"):
             SNNServeEngine(net, qparams, max_batch=N_DEV + 1, data_parallel=N_DEV)
     else:  # single device: any pool size degrades to the serial engine
-        eng = SNNServeEngine(net, qparams, max_batch=3, data_parallel=2)
+        with pytest.warns(RuntimeWarning, match="clamped to 1"):
+            eng = SNNServeEngine(net, qparams, max_batch=3, data_parallel=2)
         assert eng.data_parallel == 1
 
 
