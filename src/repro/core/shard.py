@@ -406,7 +406,8 @@ def run_int_population_sharded(
     vmapped dynamic-register sweep, so per-candidate results are bit-exact
     with the one-device sweep (and with serial ``eval_int``).  A population
     that does not divide by the shard count is padded by repeating the last
-    candidate (structurally valid work, discarded on return).
+    candidate (structurally valid work, discarded on return); that padding
+    is the profiler span ``neura.dse.shard_pad``.
     """
     dmesh = resolve_mesh(mesh)
     spikes = jnp.asarray(spikes_in)
@@ -416,12 +417,13 @@ def run_int_population_sharded(
         )
         return (counts, emitted) if return_events else counts
     n_cand = beta_regs.shape[0]
-    stacked = [
-        jax.tree.map(lambda a: pad_to_shards(a, dmesh, axis=0, mode="edge"), qp)
-        for qp in stacked_qparams
-    ]
-    beta = pad_to_shards(beta_regs, dmesh, axis=0, mode="edge")
-    alpha = pad_to_shards(alpha_regs, dmesh, axis=0, mode="edge")
+    with jax.profiler.TraceAnnotation("neura.dse.shard_pad"):
+        stacked = [
+            jax.tree.map(lambda a: pad_to_shards(a, dmesh, axis=0, mode="edge"), qp)
+            for qp in stacked_qparams
+        ]
+        beta = pad_to_shards(beta_regs, dmesh, axis=0, mode="edge")
+        alpha = pad_to_shards(alpha_regs, dmesh, axis=0, mode="edge")
     counts, emitted = _population_sharded_jit(net, stacked, beta, alpha, spikes, dmesh)
     counts, emitted = counts[:n_cand], emitted[:n_cand]
     if return_events:

@@ -13,11 +13,17 @@ SNNServeEngine` -- no jax, no device traffic, O(1) amortised per event:
   (``est_step_s``), which is the service-time estimate the scheduler's
   deadline verdicts consume, plus cumulative dispatch vs. tick wall time
   so the offered-load sweep can show where scheduling (host bookkeeping)
-  rather than compute (the jitted tick) becomes the bottleneck.
+  rather than compute (the jitted tick) becomes the bottleneck; the tick
+  wall splits further into its launch and its blocking readback.
 
 ``snapshot()`` returns one nested dict (what ``/healthz`` dashboards and
 the benchmark record); ``prometheus_text()`` renders the same state in
 Prometheus exposition format for the HTTP front-end's ``/metrics``.
+
+The serving and population hot paths also open profiler spans
+(``jax.profiler.TraceAnnotation``, under a microsecond each while no
+profile is being captured); :data:`SPAN_NAMES` lists every one, so
+traces, tests and docs name them the same way.
 """
 
 from __future__ import annotations
@@ -27,7 +33,25 @@ from collections import Counter, deque
 
 from repro.serve.scheduler import Priority
 
-__all__ = ["RollingWindow", "ServeMetrics"]
+__all__ = ["SPAN_NAMES", "RollingWindow", "ServeMetrics"]
+
+#: Profiler spans the program opens, parents before their children:
+#: ``SNNServeEngine._dispatch`` and ``tick`` (``repro.serve.snn_engine``),
+#: ``eval_int_population`` (``repro.snn.train``) and the mesh padding of
+#: ``run_int_population_sharded`` (``repro.core.shard``).
+SPAN_NAMES = (
+    "neura.serve.dispatch",
+    "neura.serve.tick",
+    "neura.serve.pack",
+    "neura.serve.launch",
+    "neura.serve.readback",
+    "neura.serve.complete",
+    "neura.dse.stack",
+    "neura.dse.batch",
+    "neura.dse.launch",
+    "neura.dse.shard_pad",
+    "neura.dse.readback",
+)
 
 
 def _percentile(values: list[float], p: float) -> float:
@@ -106,6 +130,8 @@ class ServeMetrics:
         self._est_step_s: float | None = None
         self.dispatch_s = 0.0  # cumulative host scheduling/bookkeeping wall
         self.tick_s = 0.0  # cumulative jitted-advance wall (incl. readback)
+        self.launch_s = 0.0  # the part of tick_s spent launching the advance
+        self.readback_s = 0.0  # the rest: the blocking readback of its output
         self.direct_s = 0.0  # cumulative direct event-route serve wall
         self.degrade_s = 0.0  # cumulative degraded express-batch serve wall
         self.n_ticks = 0
@@ -129,11 +155,15 @@ class ServeMetrics:
 
     def record_tick(
         self, k_steps: int, wall_s: float, queue_depth: int, active: int, n_lanes: int,
-        now: float,
+        now: float, launch_s: float = 0.0,
     ) -> None:
+        """One jitted advance of ``wall_s`` seconds, of which ``launch_s``
+        launched it and the rest read its output back."""
         self.n_ticks += 1
         self.n_steps += k_steps
         self.tick_s += wall_s
+        self.launch_s += launch_s
+        self.readback_s += wall_s - launch_s
         self.queue_depth.add(queue_depth, now)
         self.lane_occupancy.add(active / max(1, n_lanes), now)
         if k_steps > 0 and wall_s > 0:
@@ -223,6 +253,8 @@ class ServeMetrics:
             "steps": self.n_steps,
             "dispatch_s": self.dispatch_s,
             "tick_s": self.tick_s,
+            "launch_s": self.launch_s,
+            "readback_s": self.readback_s,
             "direct_s": self.direct_s,
             "degrade_s": self.degrade_s,
         }
@@ -386,4 +418,16 @@ class ServeMetrics:
             "Cumulative jitted-advance wall seconds (readback included).",
         )
         lines.append(f"neura_tick_seconds_total {self.tick_s:.6g}")
+        family(
+            "neura_tick_launch_seconds_total",
+            "counter",
+            "The part of the tick seconds spent launching the jitted advance.",
+        )
+        lines.append(f"neura_tick_launch_seconds_total {self.launch_s:.6g}")
+        family(
+            "neura_tick_readback_seconds_total",
+            "counter",
+            "The part of the tick seconds spent in the blocking readback.",
+        )
+        lines.append(f"neura_tick_readback_seconds_total {self.readback_s:.6g}")
         return "\n".join(lines) + "\n"

@@ -513,7 +513,6 @@ class SNNServeEngine:
         self._states = batched_lane_init(net, max_batch)
         self._lanes: list[_Lane | None] = [None] * max_batch
         self.n_ticks = 0  # jitted chunk dispatches
-        self.n_steps_run = 0  # simulated time steps advanced (sum of chunk lengths)
         self.n_served = 0
         self._admit_seq = 0  # first-admission counter (FIFO-order evidence)
         self._idle_rounds = 0  # consecutive no-progress polls (liveness guard)
@@ -682,8 +681,21 @@ class SNNServeEngine:
            pool is full;
         4. **admission** -- free lanes fill by class-credit DRR + tenant
            WFQ (strict FIFO under the default policy).
+
+        The round is the profiler span ``neura.serve.dispatch``, with the
+        queue depth on entry (``queued``) and the lanes it filled
+        (``admitted``).
         """
+        with jax.profiler.TraceAnnotation(
+            "neura.serve.dispatch", queued=len(self.sched)
+        ) as span:
+            done, admitted = self._dispatch_round(now)
+            span.set_metadata(admitted=admitted)
+        return done
+
+    def _dispatch_round(self, now: float) -> tuple[list[SNNRequest], int]:
         t0 = time.perf_counter()
+        admitted = 0
         served_s = 0.0  # compute spent serving, excluded from dispatch_s
         done: list[SNNRequest] = []
 
@@ -747,6 +759,7 @@ class SNNServeEngine:
                 break
             self._preempt(victim)
             self._admit(req, victim, now)
+            admitted += 1
 
         while self.sched:
             slot = self._free_lane()
@@ -756,9 +769,10 @@ class SNNServeEngine:
             if req is None:
                 break  # queue non-empty but nothing admissible: idle round
             self._admit(req, slot, now)
+            admitted += 1
 
         self.metrics.dispatch_s += time.perf_counter() - t0 - served_s
-        return done
+        return done, admitted
 
     def _admit(self, req: SNNRequest, slot: int, now: float) -> None:
         """Place a request on a free lane -- restoring its snapshotted carry
@@ -884,12 +898,41 @@ class SNNServeEngine:
         Each lane is fed its own raster slice starting at its own local
         step, so lanes admitted at different times (and with different
         window lengths) advance together through one jitted call.
+
+        A tick that runs lanes is the profiler span ``neura.serve.tick``
+        (arguments ``k``, ``active``, ``route``, ``ff_mode``), holding
+        ``pack``, ``launch``, ``readback`` and ``complete`` in that order.
         """
         active = [i for i, lane in enumerate(self._lanes) if lane is not None]
         if not active:
             return []
-        if self.faults is not None:
-            self.faults.on_tick()  # chaos: may stall, raise, or "kill"
+        with jax.profiler.TraceAnnotation("neura.serve.tick", active=len(active)) as span:
+            if self.faults is not None:
+                self.faults.on_tick()  # chaos: may stall, raise, or "kill"
+            with jax.profiler.TraceAnnotation("neura.serve.pack"):
+                k, x, meta, budget, ff_mode = self._pack(active)
+            span.set_metadata(
+                k=k, route="dense" if budget is None else "sparse", ff_mode=ff_mode
+            )
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("neura.serve.launch"):
+                self._states, packed = _lane_window_packed(
+                    self.net, self.qparams, self._states, x, meta, ff_mode, self._dmesh,
+                    budget,
+                )
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("neura.serve.readback"):
+                packed = np.asarray(packed)  # [k, n_lanes, n_classes + n_layers]
+            t2 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("neura.serve.complete") as done:
+                finished = self._complete(active, k, meta, packed, t2 - t0, t1 - t0)
+                done.set_metadata(finished=len(finished))
+        return finished
+
+    def _pack(self, active: list[int]):
+        """The tick's inputs: chunk length ``k``, the lanes' raster slices
+        ``x``, their (reset, valid steps) ``meta``, and the program to run
+        (sparse event ``budget`` or None, feed-forward lowering)."""
         k = self._chunk_len(active)
         dtype = (
             np.uint8
@@ -932,19 +975,19 @@ class SNNServeEngine:
                 and all(self._lanes[i].req._max_val <= self._f32_input_max for i in active)
                 else "int32"
             )
-        t0 = time.perf_counter()
-        self._states, packed = _lane_window_packed(
-            self.net, self.qparams, self._states, x, meta, ff_mode, self._dmesh, budget
-        )
-        packed = np.asarray(packed)  # [k, n_lanes, n_classes + n_layers]
-        tick_wall = time.perf_counter() - t0
+        return k, x, meta, budget, ff_mode
+
+    def _complete(
+        self, active: list[int], k: int, meta: np.ndarray, packed: np.ndarray,
+        tick_wall: float, launch_s: float,
+    ) -> list[SNNRequest]:
+        """Book the tick's read-back outputs into its lanes; returns finished."""
         n_classes = self.net.n_classes
         self.n_ticks += 1
-        self.n_steps_run += k
         finished = []
         now = time.perf_counter()
         self.metrics.record_tick(
-            k, tick_wall, len(self.sched), len(active), self.max_batch, now
+            k, tick_wall, len(self.sched), len(active), self.max_batch, now, launch_s
         )
         for i in active:
             lane = self._lanes[i]

@@ -303,12 +303,18 @@ def eval_int_population(
     each device sweeps its slice of the population through the identical
     vmapped program, so per-candidate results stay bit-exact with both the
     one-device sweep and serial :func:`eval_int` (see ``repro.core.shard``).
+
+    Profiler spans: ``neura.dse.stack`` (argument ``candidates``) around
+    stacking the population, then one ``neura.dse.batch`` per data batch
+    (``index``, ``samples``) holding its ``neura.dse.launch`` and
+    ``neura.dse.readback``; the rest of a batch is the host reduction.
     """
-    backend_lib.check_population_structure(net, candidate_nets)
-    stacked, beta_regs, alpha_regs = backend_lib.stack_population(
-        candidate_nets, qparams_list
-    )
-    dmesh = shard_lib.resolve_mesh(mesh)
+    with jax.profiler.TraceAnnotation("neura.dse.stack", candidates=len(candidate_nets)):
+        backend_lib.check_population_structure(net, candidate_nets)
+        stacked, beta_regs, alpha_regs = backend_lib.stack_population(
+            candidate_nets, qparams_list
+        )
+        dmesh = shard_lib.resolve_mesh(mesh)
     if dmesh is not None and dmesh.n_shards > 1:
         def pop_fwd(spikes):
             counts, emitted = shard_lib.run_int_population_sharded(
@@ -328,16 +334,19 @@ def eval_int_population(
     total = 0
     layer_ev = None  # [P, T, L] running size-weighted sum of batch means
     in_ev = None  # [T]
-    for spikes, labels in ds.batches(batch_size):
-        preds, evs, iev = pop_fwd(jnp.asarray(spikes))
-        preds = np.asarray(preds)
-        correct += (preds == labels[None, :]).sum(axis=1)
+    for index, (spikes, labels) in enumerate(ds.batches(batch_size)):
         n = len(labels)
-        total += n
-        # size-weighted like eval_int: partial batches must not bias traffic
-        evs, iev = np.asarray(evs) * n, np.asarray(iev) * n
-        layer_ev = evs if layer_ev is None else layer_ev + evs
-        in_ev = iev if in_ev is None else in_ev + iev
+        with jax.profiler.TraceAnnotation("neura.dse.batch", index=index, samples=n):
+            with jax.profiler.TraceAnnotation("neura.dse.launch"):
+                out = pop_fwd(jnp.asarray(spikes))
+            with jax.profiler.TraceAnnotation("neura.dse.readback"):
+                preds, evs, iev = (np.asarray(a) for a in out)
+            correct += (preds == labels[None, :]).sum(axis=1)
+            total += n
+            # size-weighted like eval_int: partial batches must not bias traffic
+            evs, iev = evs * n, iev * n
+            layer_ev = evs if layer_ev is None else layer_ev + evs
+            in_ev = iev if in_ev is None else in_ev + iev
     accs = correct / max(1, total)
     if not return_stats:
         return accs

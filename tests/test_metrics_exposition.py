@@ -50,6 +50,7 @@ def _populated_metrics():
         m.inc(k)
     m.recovering = 1
     m.recovery_s = 0.25
+    m.record_tick(8, 0.003, queue_depth=1, active=2, n_lanes=4, now=0.0, launch_s=0.001)
     return m
 
 
@@ -136,3 +137,13 @@ def test_preexisting_families_kept_their_names_and_gained_metadata():
     ):
         assert family in types and family in helps
         assert family in names, f"{family} lost its samples"
+
+
+def test_tick_launch_and_readback_families_split_the_tick_seconds():
+    helps, types, samples = _parse(_populated_metrics().prometheus_text())
+    value = {name: float(v) for name, labels, v in samples if not labels}
+    for family in ("neura_tick_launch_seconds_total", "neura_tick_readback_seconds_total"):
+        assert types[family] == "counter" and family in helps
+    assert value["neura_tick_launch_seconds_total"] == 0.001
+    assert value["neura_tick_readback_seconds_total"] == 0.002
+    assert value["neura_tick_seconds_total"] == 0.003
