@@ -879,6 +879,40 @@ def check_population_structure(base, nets) -> None:
                     )
 
 
+def _population_registers(nets) -> np.ndarray:
+    """Host int32 ``[P, 2, n_layers]``: each candidate's packed beta and
+    alpha DecayRate registers (pure config arithmetic, no device work)."""
+    return np.asarray(
+        [
+            [
+                [cfg.beta_code().decay_rate_register for cfg in net.layers],
+                [cfg.alpha_code().decay_rate_register for cfg in net.layers],
+            ]
+            for net in nets
+        ],
+        np.int32,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("pad", "flat"))
+def _stack_population_jit(qparams_list, regs, pad: int = 0, flat: bool = False):
+    """The whole population build as one program.
+
+    Stacks every leaf of ``qparams_list`` (P per-candidate parameter lists)
+    along a new leading candidate axis and splits ``regs`` (see
+    :func:`_population_registers`) into the beta and alpha registers.
+    ``pad`` repeats the last candidate that many times (a mesh's shard
+    remainder); ``flat`` packs every output into one int32 ``[P + pad, F]``
+    buffer, so a mesh placement moves the population in one transfer.
+    """
+    out = (jax.tree.map(lambda *xs: jnp.stack(xs), *qparams_list), regs[:, 0], regs[:, 1])
+    if pad:
+        out = jax.tree.map(lambda a: jnp.concatenate([a, jnp.repeat(a[-1:], pad, axis=0)]), out)
+    if flat:
+        return jnp.concatenate([a.reshape(a.shape[0], -1) for a in jax.tree.leaves(out)], axis=1)
+    return out
+
+
 def stack_population(nets, qparams_list):
     """Stack per-candidate quantized parameters for a vmapped evaluation.
 
@@ -889,25 +923,13 @@ def stack_population(nets, qparams_list):
     ``quantize_params`` outputs.  Returns ``(stacked_qparams, beta_regs,
     alpha_regs)`` where each stacked leaf gains a leading candidate axis and
     the decay registers are int32 ``[P, n_layers]`` packed DecayRate values.
+
+    The population is built by one program (``_stack_population_jit``),
+    with the decay registers computed on the host and carried in with it,
+    on the default device; ``repro.core.shard.stack_population_sharded``
+    runs the same build and places it on a mesh once.
     """
-    n_layers = len(nets[0].layers)
-    stacked = [
-        IntLayerParams(
-            w_ff=jnp.stack([qp[l].w_ff for qp in qparams_list]),
-            w_rec=jnp.stack([qp[l].w_rec for qp in qparams_list]),
-            theta_q=jnp.stack([qp[l].theta_q for qp in qparams_list]),
-        )
-        for l in range(n_layers)
-    ]
-    beta_regs = jnp.asarray(
-        [[cfg.beta_code().decay_rate_register for cfg in net.layers] for net in nets],
-        jnp.int32,
-    )
-    alpha_regs = jnp.asarray(
-        [[cfg.alpha_code().decay_rate_register for cfg in net.layers] for net in nets],
-        jnp.int32,
-    )
-    return stacked, beta_regs, alpha_regs
+    return _stack_population_jit([list(qp) for qp in qparams_list], _population_registers(nets))
 
 
 def _run_int_dynamic(net, qparams, beta_regs, alpha_regs, spikes_in):

@@ -46,20 +46,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.backend import (
     InferenceBackend,
     SimRecord,
+    _population_registers,
     _run_int_batched_jit,
+    _stack_population_jit,
     get_backend,
     run_int_batched,
     run_int_population,
+    stack_population,
 )
 from repro.distributed import compat
 
@@ -72,6 +76,7 @@ __all__ = [
     "allgather_hosts",
     "run_int_sharded",
     "run_float_sharded",
+    "stack_population_sharded",
     "run_int_population_sharded",
     "run_int_batched_sharded",
     "wrap_lane_window",
@@ -396,6 +401,46 @@ def _population_sharded_jit(net, stacked, beta_regs, alpha_regs, spikes, dmesh):
     return fn(tuple(stacked), beta_regs, alpha_regs, spikes)
 
 
+@functools.partial(jax.jit, static_argnames=("shapes", "sharding"))
+def _unflatten_population_jit(flat, shapes, sharding):
+    # column slices of a buffer split along its rows: every device unpacks
+    # its own candidates, nothing crosses between devices
+    leaves, at = [], 0
+    for shape in shapes:
+        width = math.prod(shape)
+        leaf = flat[:, at : at + width].reshape(flat.shape[0], *shape)
+        leaves.append(jax.lax.with_sharding_constraint(leaf, sharding))
+        at += width
+    return leaves
+
+
+def stack_population_sharded(nets, qparams_list, mesh):
+    """``stack_population``, padded to the shard count and placed on ``mesh``.
+
+    The same one-program build, with the candidate axis padded by repeating
+    the last candidate and every output packed into one int32 buffer; that
+    buffer moves onto the mesh in one transfer, split along the candidate
+    axis, and one program there unpacks it into the stacked leaves and
+    decay registers, each with ``NamedSharding(mesh, P(axis))`` -- the
+    layout :func:`run_int_population_sharded` reads, so its launches move
+    only their spikes.  A mesh of one device returns ``stack_population``'s
+    result unchanged.
+    """
+    dmesh = resolve_mesh(mesh)
+    if dmesh is None or dmesh.n_shards == 1:
+        return stack_population(nets, qparams_list)
+    qparams_list = [list(qp) for qp in qparams_list]
+    sharding = NamedSharding(dmesh.mesh, P(dmesh.axis))
+    flat = _stack_population_jit(
+        qparams_list, _population_registers(nets), pad=dmesh.pad(len(nets)), flat=True
+    )
+    n_layers = len(qparams_list[0])
+    shapes = tuple(a.shape for a in jax.tree.leaves(qparams_list[0])) + ((n_layers,),) * 2
+    leaves = _unflatten_population_jit(jax.device_put(flat, sharding), shapes, sharding)
+    stacked = jax.tree.unflatten(jax.tree.structure(qparams_list[0]), leaves[:-2])
+    return stacked, leaves[-2], leaves[-1]
+
+
 def run_int_population_sharded(
     net, stacked_qparams, beta_regs, alpha_regs, spikes_in, mesh,
     return_events: bool = False,
@@ -407,7 +452,9 @@ def run_int_population_sharded(
     with the one-device sweep (and with serial ``eval_int``).  A population
     that does not divide by the shard count is padded by repeating the last
     candidate (structurally valid work, discarded on return); that padding
-    is the profiler span ``neura.dse.shard_pad``.
+    is the profiler span ``neura.dse.shard_pad``.  A population from
+    :func:`stack_population_sharded` is already padded and placed, and
+    passes through without a copy.
     """
     dmesh = resolve_mesh(mesh)
     spikes = jnp.asarray(spikes_in)

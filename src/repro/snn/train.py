@@ -304,17 +304,24 @@ def eval_int_population(
     vmapped program, so per-candidate results stay bit-exact with both the
     one-device sweep and serial :func:`eval_int` (see ``repro.core.shard``).
 
-    Profiler spans: ``neura.dse.stack`` (argument ``candidates``) around
-    stacking the population, then one ``neura.dse.batch`` per data batch
-    (``index``, ``samples``) holding its ``neura.dse.launch`` and
+    Each call builds its population in one program and, on a mesh, pads
+    and places it over the devices once (``stack_population_sharded``), so
+    a batch's launch moves only that batch's spikes.
+
+    Profiler spans: ``neura.dse.stack`` (arguments ``candidates`` and
+    ``shards``, the devices the population was placed over) around building
+    the population, then one ``neura.dse.batch`` per data batch (``index``,
+    ``samples``) holding its ``neura.dse.launch`` and
     ``neura.dse.readback``; the rest of a batch is the host reduction.
     """
-    with jax.profiler.TraceAnnotation("neura.dse.stack", candidates=len(candidate_nets)):
+    P = len(candidate_nets)
+    with jax.profiler.TraceAnnotation("neura.dse.stack", candidates=P) as span:
         backend_lib.check_population_structure(net, candidate_nets)
-        stacked, beta_regs, alpha_regs = backend_lib.stack_population(
-            candidate_nets, qparams_list
-        )
         dmesh = shard_lib.resolve_mesh(mesh)
+        stacked, beta_regs, alpha_regs = shard_lib.stack_population_sharded(
+            candidate_nets, qparams_list, dmesh
+        )
+        span.set_metadata(shards=1 if dmesh is None else dmesh.n_shards)
     if dmesh is not None and dmesh.n_shards > 1:
         def pop_fwd(spikes):
             counts, emitted = shard_lib.run_int_population_sharded(
@@ -329,7 +336,6 @@ def eval_int_population(
         def pop_fwd(spikes):
             return _population_fwd(net, stacked, beta_regs, alpha_regs, spikes)
 
-    P = len(candidate_nets)
     correct = np.zeros(P, np.int64)
     total = 0
     layer_ev = None  # [P, T, L] running size-weighted sum of batch means
@@ -341,6 +347,7 @@ def eval_int_population(
                 out = pop_fwd(jnp.asarray(spikes))
             with jax.profiler.TraceAnnotation("neura.dse.readback"):
                 preds, evs, iev = (np.asarray(a) for a in out)
+            preds, evs = preds[:P], evs[:P]  # a mesh's padding candidates
             correct += (preds == labels[None, :]).sum(axis=1)
             total += n
             # size-weighted like eval_int: partial batches must not bias traffic

@@ -194,6 +194,7 @@ def test_population_spans_one_stack_and_one_batch_per_batch(dse_trace):
     (_, events), _ = dse_trace
     stack = [e for e in events if e[0] == "neura.dse.stack"]
     assert len(stack) == 1 and stack[0][3]["candidates"] == 3
+    assert stack[0][3]["shards"] == 1
     batches = [e for e in events if e[0] == "neura.dse.batch"]
     assert [(b[3]["index"], b[3]["samples"]) for b in batches] == [(0, 16), (1, 16), (2, 8)]
     assert stack[0][2] <= batches[0][1]
@@ -214,24 +215,24 @@ def test_population_outputs_identical_with_the_profiler_on(dse_trace):
 
 _MESH_PROG = """
 import os, sys, json, pathlib
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, {tests!r})
 import jax
 from test_serve_tracing import _dse_case, _host_events, _traced
 from repro.snn.train import eval_int_population
 
-assert len(jax.devices()) == 2
+assert len(jax.devices()) == 4
 net, cands, qps, ds = _dse_case()
-sweep = lambda: eval_int_population(net, cands, qps, ds, batch_size=16, mesh=2)
+sweep = lambda: eval_int_population(net, cands, qps, ds, batch_size=16, mesh=4)
 sweep()
 _, events = _traced(pathlib.Path({path!r}), sweep)
-print(json.dumps([e[:3] for e in events]))
+print(json.dumps(events))
 """
 
 
 @pytest.fixture(scope="module")
 def mesh_events(tmp_path_factory):
-    """The same sweep over a mesh of two forced host devices, in a fresh interpreter."""
+    """The same sweep over a mesh of four forced host devices, in a fresh interpreter."""
     if jax.default_backend() != "cpu":
         pytest.skip("forces host devices")
     path = tmp_path_factory.mktemp("mesh_trace")
@@ -250,6 +251,11 @@ def test_mesh_padding_is_a_child_of_the_launch(mesh_events):
     assert len(pads) == len(launches) == 3
     for pad, launch in zip(pads, launches):
         assert launch[1] <= pad[1] and pad[2] <= launch[2]
+
+
+def test_stack_span_counts_the_devices_the_population_was_placed_over(mesh_events):
+    (stack,) = [e for e in mesh_events if e[0] == "neura.dse.stack"]
+    assert stack[3]["candidates"] == 3 and stack[3]["shards"] == 4
 
 
 def test_every_span_name_is_emitted_and_none_is_a_harness_name(
