@@ -25,6 +25,14 @@ a user calls:
 5. dse+qat -- ``eval_int_population`` over 16 candidate precisions, each
    equal to its own serial ``eval_int``; then ``train_snn(qat=...)`` for one
    epoch of a few batches, with a finite loss.
+6. shd     -- the SHD network of ``perfbench/configs/shd-syn-atat-700-200-20.json``
+   (700-200-20, Synaptic, ATA-T hidden layer split over three fan-in cores,
+   T=100) on 256 ``shd_like`` samples at 700 channels: ``eval_int`` through
+   the ``fused``, ``event`` and ``EventBackend("pallas")`` backends and
+   ``eval_int_population`` equal to ``reference``; the 700-wide int32 dot
+   equal to numpy at ``w_bits=16``, where the f32 lowering must be refused;
+   ``SNNServeEngine`` serving 32 of the samples, each equal to a serial
+   ``run_int``.
 
 With ``--chips 4`` only the sharded paths run, each against its one-device
 form: ``SNNServeEngine(data_parallel=4)``, ``eval_int(mesh=4)`` and
@@ -60,6 +68,7 @@ HIDDEN = 128
 BATCH = 1024
 N_REQUESTS = 64  # per traffic kind
 LANES = 8
+SHD_CONFIG = "shd-syn-atat-700-200-20"
 
 
 class _CompileStats:
@@ -316,6 +325,88 @@ def phase_dse_qat(stats, net, params):
         )
 
 
+def _shd_net():
+    from repro.core.network import NetworkConfig
+    from repro.core.snn_layer import LayerConfig, NeuronModel, ResetMode, Topology
+
+    cfg = json.loads((ROOT / "perfbench" / "configs" / f"{SHD_CONFIG}.json").read_text())
+    enums = {"neuron": NeuronModel, "topology": Topology, "reset": ResetMode}
+    layers = tuple(
+        LayerConfig(**{k: enums[k](v) if k in enums else v for k, v in layer.items()})
+        for layer in cfg["layers"]
+    )
+    return NetworkConfig(layers=layers, n_steps=cfg["n_steps"], name=cfg["name"]), cfg
+
+
+def phase_shd(stats):
+    from repro.core import lowering
+    from repro.core.backend import EventBackend
+    from repro.core.network import init_float_params, quantize_params, run_int
+    from repro.data.snn_datasets import shd_like
+    from repro.serve.snn_engine import SNNRequest, SNNServeEngine
+    from repro.snn.train import eval_int, eval_int_population
+
+    with Phase(stats, "6 shd") as ph:
+        net, cfg = _shd_net()
+        gain = cfg["init"]["ff_gain"]  # the benchmark's feed-forward scale
+        params = [
+            p._replace(w_ff=p.w_ff * gain)
+            for p in init_float_params(jax.random.PRNGKey(SEED + 4), net)
+        ]
+        qparams, _ = quantize_params(net, params)
+        ds = shd_like(n=256, T=net.n_steps, seed=SEED + 4, channels=net.n_in)
+        ph.note(f"{net.name}: {net.n_cores} cores, layer 1 on {net.layers[0].n_cores}")
+
+        xs = ds.spikes.reshape(-1, net.n_in).astype(np.int64)
+        w16 = np.asarray(quantize_params(net.replace_precisions(w_bits=16), params)[0][0].w_ff)
+        got = np.asarray(jnp.einsum("mk,kn->mn", jnp.asarray(xs, jnp.int32), jnp.asarray(w16)))
+        check(np.array_equal(got, xs @ w16.astype(np.int64)), "700-wide int32 dot != numpy")
+        check(not lowering.f32_exact(16, 1, net.n_in), "f32 certified for 700 x w_bits=16")
+        ph.note("700-wide int32 dot at w_bits=16 == numpy; f32 refused")
+
+        ref = eval_int(net, qparams, ds, batch_size=256, return_stats=True)
+        for name, backend in (
+            ("fused", "fused"),
+            ("event", "event"),
+            ("event-pallas", EventBackend("pallas")),
+        ):
+            acc, st = eval_int(net, qparams, ds, batch_size=256, return_stats=True, backend=backend)
+            same = acc == ref[0]
+            for a, b in zip(st["layer_events_per_step"], ref[1]["layer_events_per_step"]):
+                same &= np.array_equal(a, b)
+            check(same, f"shd eval_int {name} != reference")
+        events = ref[1]["layer_events_per_step"]
+        rates = " ".join(f"{np.mean(e) / c.n_out:.4f}" for e, c in zip(events, net.layers))
+        ph.note(f"eval_int fused/event/event-pallas == reference: acc {ref[0]:.4f}, rates {rates}")
+
+        cands = [net.replace_precisions(w_bits=b, w_rec_bits=b) for b in (4, 8, 12, 16)]
+        qps = [quantize_params(c, params)[0] for c in cands]
+        pop = eval_int_population(net, cands, qps, ds, batch_size=256)
+        serial = np.asarray([eval_int(c, q, ds, batch_size=256) for c, q in zip(cands, qps)])
+        check(np.array_equal(pop, serial), f"shd population {pop} != serial {serial}")
+        ph.note(f"eval_int_population over {len(cands)} candidates == serial eval_int")
+
+        engine = SNNServeEngine(net, qparams, max_batch=LANES)
+        engine.warmup()
+        rasters = [ds.spikes[i] for i in range(32)]
+        done = engine.run([SNNRequest(uid=i, raster=r) for i, r in enumerate(rasters)])
+        check(len(done) == len(rasters), f"shd served {len(done)} of {len(rasters)}")
+        serial = serial_counts(net, qparams, net.n_steps)
+        for req in done:
+            check(req.status == "completed", f"shd request {req.uid} {req.status}")
+            check(
+                np.array_equal(req.spike_counts, serial(rasters[req.uid])),
+                f"shd request {req.uid} != serial run_int",
+            )
+        batch = run_int(net, qparams, jnp.asarray(np.transpose(ds.spikes[:32], (1, 0, 2))))
+        served = np.stack([r.spike_counts for r in sorted(done, key=lambda r: r.uid)])
+        check(np.array_equal(np.asarray(batch.spike_counts), served), "shd served != batch run_int")
+        ph.note(
+            f"SNNServeEngine {len(rasters)} requests, lowerings {engine.route_lowerings()}; "
+            "every request == serial run_int"
+        )
+
+
 def one_chip(stats, net, params):
     from repro.core.network import quantize_params
     from repro.data.snn_datasets import mnist_like
@@ -328,6 +419,7 @@ def one_chip(stats, net, params):
     engine = phase_serve(stats, net, qparams, rng)
     phase_stream(stats, net, qparams, engine, rng)
     phase_dse_qat(stats, net, params)
+    phase_shd(stats)
 
 
 # ---------------------------------------------------------------------------

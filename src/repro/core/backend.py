@@ -98,6 +98,7 @@ import numpy as np
 
 from repro.core import lowering
 from repro.core.snn_layer import (
+    FF_SCOPE,
     IntLayerParams,
     ResetMode,
     fused_eligible,
@@ -320,14 +321,15 @@ class FusedBackend(InferenceBackend):
         use_pallas = self._pallas_enabled()
         interpret = lowering.interpret(self.interpret) if use_pallas else False
         feed = lowering.mxu_feed(cfg.w_bits, max_val) if use_pallas else lowering.XLA_INT32
-        currents = spike_integrate(
-            raster,
-            p.w_ff,
-            w_bits=cfg.w_bits,
-            max_val=max_val,
-            use_pallas=use_pallas,
-            interpret=interpret,
-        )
+        with jax.named_scope(FF_SCOPE):
+            currents = spike_integrate(
+                raster,
+                p.w_ff,
+                w_bits=cfg.w_bits,
+                max_val=max_val,
+                use_pallas=use_pallas,
+                interpret=interpret,
+            )
         code = cfg.beta_code()
         decay_k = 256 if code.bypass else code.k
         reset_to_zero = cfg.reset == ResetMode.ZERO
@@ -453,7 +455,8 @@ def _csr_currents(
 
 @functools.partial(jax.jit, static_argnames=("cfg", "k_active"))
 def _event_layer_window(cfg, params: IntLayerParams, raster, k_active: int):
-    currents = _gather_currents(raster, params.w_ff, k_active)
+    with jax.named_scope(FF_SCOPE):
+        currents = _gather_currents(raster, params.w_ff, k_active)
     return int_layer_window_from_currents(cfg, params, currents)
 
 
@@ -467,7 +470,8 @@ def _dense_layer_window(cfg, params: IntLayerParams, raster):
     """Density fallback: whole-window flat dense integration (one einsum
     over [T*B, n_in], the fused backend's shape) feeding the same step scan
     -- so even the fallback beats the step-major reference on wall-clock."""
-    currents = spike_integrate(raster, params.w_ff)
+    with jax.named_scope(FF_SCOPE):
+        currents = spike_integrate(raster, params.w_ff)
     return int_layer_window_from_currents(cfg, params, currents)
 
 
@@ -483,14 +487,15 @@ def _fixed_layer_window(cfg, params: IntLayerParams, raster, budget, how, interp
     window the pallas strategy runs under an outer ``jax.jit`` /
     ``shard_map``.
     """
-    if how == lowering.PALLAS_SPARSE:
-        currents = sparse_accum_currents(
-            raster, params.w_ff, budget, use_pallas=True, interpret=interpret
-        )
-    elif how == lowering.F32:
-        currents = lowering.f32_currents(raster, params.w_ff)
-    else:
-        currents = spike_integrate(raster, params.w_ff)
+    with jax.named_scope(FF_SCOPE):
+        if how == lowering.PALLAS_SPARSE:
+            currents = sparse_accum_currents(
+                raster, params.w_ff, budget, use_pallas=True, interpret=interpret
+            )
+        elif how == lowering.F32:
+            currents = lowering.f32_currents(raster, params.w_ff)
+        else:
+            currents = spike_integrate(raster, params.w_ff)
     return int_layer_window_from_currents(cfg, params, currents)
 
 
@@ -1105,12 +1110,13 @@ def batched_lane_window(
         live = jnp.arange(k)[:, None] < valid_steps[None, :]  # [k, n_lanes]
     new_states, emitted = [], []
     for li, (cfg, p, st) in enumerate(zip(net.layers, qparams, states)):
-        if li == 0 and event_budget is not None:
-            currents = sparse_accum_currents(x, p.w_ff, min(event_budget, cfg.n_in))
-        elif ff_mode == "f32_exact":
-            currents = lowering.f32_currents(x, p.w_ff)
-        else:
-            currents = spike_integrate(x, p.w_ff)
+        with jax.named_scope(FF_SCOPE):
+            if li == 0 and event_budget is not None:
+                currents = sparse_accum_currents(x, p.w_ff, min(event_budget, cfg.n_in))
+            elif ff_mode == "f32_exact":
+                currents = lowering.f32_currents(x, p.w_ff)
+            else:
+                currents = spike_integrate(x, p.w_ff)
         st, x = int_layer_window_carry(cfg, p, st, currents, live=live)
         new_states.append(st)
         emitted.append(jnp.sum(x, axis=-1))  # [k, n_lanes]

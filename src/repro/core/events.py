@@ -33,6 +33,13 @@ saturates (and the strict model is the ground truth when one does).  Its
 ``cycle_count`` (one cycle per swept neuron visit) is the same accounting
 rule the analytic latency model in ``repro.core.hw_model.step_cycles``
 vectorises.
+
+Addresses stay 8-bit per core: a layer wider than 256 inputs or neurons is
+several cores (:class:`SplitEventLayer`, one :class:`EventDrivenCore` per
+``LayerConfig.core_slices`` entry).  The first core of each neuron slice
+holds the state; the others run FF-Integ on their own address slice into
+int32 partial currents, which the state core merges (one integration per
+neuron and partial) before its REC-Integ and leak/spike sweep.
 """
 
 from __future__ import annotations
@@ -51,6 +58,7 @@ __all__ = [
     "decode_packet",
     "raster_to_packets",
     "EventDrivenCore",
+    "SplitEventLayer",
 ]
 
 _CONTROL_BIT = 1 << 8
@@ -148,6 +156,23 @@ class EventDrivenCore:
         for n in range(self.cfg.n_out):
             self._integrate_one(n, int(self.w_ff[src, n]))
 
+    def fan_in_currents(self, aspl_sources: list[int]) -> np.ndarray:
+        """FF-Integ of a core that holds no neuron state: int32 partial currents.
+
+        Sweeps every destination neuron per event, as :meth:`integrate_aspl`
+        does, into a partial-current buffer that the state core merges.
+        """
+        acc = np.zeros(self.cfg.n_out, np.int64)
+        for src in aspl_sources:
+            acc += self.w_ff[src]
+            self.cycle_count += self.cfg.n_out
+        return acc
+
+    def merge(self, partial: np.ndarray):
+        """Integrate another core's partial currents: one event per neuron."""
+        for n in range(self.cfg.n_out):
+            self._integrate_one(n, int(partial[n]))
+
     def integrate_ascl(self, src: int):
         """REC-Integ: dense sweep (ATA-T) or self-only update (ATA-F)."""
         if self.cfg.topology == Topology.ATA_T:
@@ -188,10 +213,16 @@ class EventDrivenCore:
             self.i_syn[:] = 0
         return fired
 
-    def step(self, aspl_sources: list[int], last: bool = False) -> list[int]:
-        """Process one full time step worth of packets; returns fired addrs."""
+    def step(self, aspl_sources: list[int], last: bool = False, partials=()) -> list[int]:
+        """Process one full time step worth of packets; returns fired addrs.
+
+        ``partials`` are the partial currents of the cores that share this
+        core's neurons (:class:`SplitEventLayer`), merged after its own events.
+        """
         for src in aspl_sources:
             self.integrate_aspl(src)
+        for partial in partials:
+            self.merge(partial)
         # EOTS/EOIN: recurrent events from the previous step, then leak/spike.
         if self.cfg.is_recurrent:
             for src in np.nonzero(self.prev_spk)[0]:
@@ -200,4 +231,47 @@ class EventDrivenCore:
         self.prev_spk[:] = 0
         if not last:
             self.prev_spk[fired] = 1
+        return fired
+
+
+class SplitEventLayer:
+    """A layer wider than one core, as the cores of ``cfg.core_slices()`` plus the merge.
+
+    Each core keeps 8-bit local addresses: the input addresses of its slice
+    less the slice's start.  Per neuron slice, the first core is the state
+    core (recurrence, leak/spike sweep); the others send it their partial
+    currents.  ``step`` takes and returns the layer's global addresses.
+    """
+
+    def __init__(self, cfg: LayerConfig, w_ff, w_rec, theta_q: int):
+        w_ff = np.asarray(w_ff)
+        self.cfg = cfg
+        self.slices = cfg.core_slices()
+        self.cores = []
+        for rows, cols, sub in self.slices:
+            rec = w_rec
+            if sub.topology == Topology.ATA_T:
+                rec = np.asarray(w_rec)[np.ix_(cols, cols)]
+            self.cores.append(EventDrivenCore(sub, w_ff[np.ix_(rows, cols)], rec, theta_q))
+
+    @property
+    def cycle_count(self) -> int:
+        return sum(core.cycle_count for core in self.cores)
+
+    def step(self, aspl_sources: list[int], last: bool = False) -> list[int]:
+        """One time step of the whole layer; returns the fired global addresses."""
+        fired, partials = [], []
+        for (rows, cols, _), core in zip(self.slices, self.cores):
+            # the ASPL word is the core's local address; encoding checks it fits 8 bits
+            local = [
+                encode_packet(PacketKind.ASPL, a - rows.start) for a in aspl_sources if a in rows
+            ]
+            if rows.start:
+                partials.append(core.fan_in_currents(local))
+            else:
+                state_core, state_cols, state_local = core, cols, local
+            if rows.stop == self.cfg.n_in:  # the neuron slice's last core: merge and fire
+                out = state_core.step(state_local, last=last, partials=partials)
+                fired += [state_cols.start + n for n in out]
+                partials = []
         return fired

@@ -28,6 +28,15 @@ design point reproduces exactly and a regression test can hold it):
   energy per synaptic event is solved from the 0.12 mJ figure at the anchor
   traffic (``_solve_event_switching_power``).
 
+A layer wider than one core (``LayerConfig.core_slices``) is charged core by
+core: each physical core's resources at its own slice, and in the cycle
+model the fan-in cores of a neuron slice integrate their share of the input
+events in parallel before the state core merges their partial currents (one
+visit per neuron and partial core).  With no per-address traffic measured,
+a fan-in core's share of a layer's input events is its share of the input
+addresses.  A layer that fits one core costs exactly what it did before the
+split existed.
+
 Latency and energy are therefore functions of *measured event traffic*
 (:class:`EventTraffic`, built from any backend's ``SimRecord`` or from
 ``eval_int(..., return_stats=True)``), which is what lets the Flex-plorer
@@ -213,7 +222,8 @@ def network_resources(net: NetworkConfig) -> CoreResources:
     # design point per completed request against one fixed network
     total = CoreResources(0.0, 0.0, 0)
     for cfg in net.layers:
-        total = total + core_resources(cfg)
+        for _, _, core in cfg.core_slices():
+            total = total + core_resources(core)
     return total
 
 
@@ -295,20 +305,32 @@ CLOCK_HZ = 60e6
 _CONTROLLER_OVERHEAD_CYCLES = 100  # per step per core (paper's controller loop)
 
 
-def step_cycles(cfg: LayerConfig, n_in_events: float, n_rec_events: float) -> float:
-    """Cycles one core spends on one time step.
+def step_cycles(cfg: LayerConfig, n_in_events, n_rec_events):
+    """Cycles one layer's cores spend on one time step (scalars or ``[T]`` arrays).
 
     FF-Integ sweeps all n_out neurons per incoming ASPL; REC-Integ sweeps
     n_out per ASCL under ATA-T but only the source neuron under ATA-F; the
-    Leak/Spike phase visits every neuron once.
+    Leak/Spike phase visits every neuron once.  A layer split over several
+    cores takes, per neuron slice, its slowest fan-in core's FF-Integ, then
+    the state core's merge of the other fan-in cores' partial currents and
+    its REC-Integ and sweep; the slowest neuron slice sets the step.
     """
-    cycles = n_in_events * cfg.n_out
-    if cfg.topology == Topology.ATA_T:
-        cycles += n_rec_events * cfg.n_out
-    elif cfg.topology == Topology.ATA_F:
-        cycles += n_rec_events
-    cycles += cfg.n_out  # leak / spike-generation sweep
-    return cycles + _CONTROLLER_OVERHEAD_CYCLES
+    cycles = 0.0
+    for rows, cols, core in cfg.core_slices():
+        if rows.start:
+            continue  # fan-in cores are counted with their state core below
+        width = len(cols)
+        ff = n_in_events * (len(rows) / cfg.n_in) * width  # the first slice is the widest
+        merge = (cfg.fan_in_cores - 1) * width
+        rec = 0.0
+        if core.topology == Topology.ATA_T:
+            rec = n_rec_events * width
+        elif core.topology == Topology.ATA_F:
+            rec = n_rec_events * (width / cfg.n_out)
+        # leak / spike-generation sweep, controller loop
+        total = ff + merge + rec + width + _CONTROLLER_OVERHEAD_CYCLES
+        cycles = np.maximum(cycles, total)
+    return cycles
 
 
 def latency_seconds(
@@ -338,18 +360,11 @@ def latency_seconds(
             if li == 0
             else traffic.layer_events_per_step[li - 1]
         )
-        # Recurrent events consumed at step t are the spikes of step t-1
-        # (vectorised form of ``step_cycles`` over the window; identical
-        # arithmetic, held together by test_snn_core's latency tests).
+        # Recurrent events consumed at step t are the spikes of step t-1.
         rec_ev = np.zeros(T)
         if cfg.is_recurrent:
             rec_ev[1:] = traffic.layer_events_per_step[li][:-1]
-        cycles = in_ev * cfg.n_out
-        if cfg.topology == Topology.ATA_T:
-            cycles = cycles + rec_ev * cfg.n_out
-        elif cfg.topology == Topology.ATA_F:
-            cycles = cycles + rec_ev
-        per_core_step_cycles[li] = cycles + cfg.n_out + _CONTROLLER_OVERHEAD_CYCLES
+        per_core_step_cycles[li] = step_cycles(cfg, in_ev, rec_ev)
     steady = per_core_step_cycles.max(axis=0).sum()
     fill = sum(
         per_core_step_cycles[li, 0] for li in range(len(net.layers) - 1)
@@ -438,6 +453,8 @@ def bandwidth_profile(net: NetworkConfig, traffic: EventTraffic) -> BandwidthPro
             bytes_per_step = bytes_per_step + rec_ev * (cfg.w_rec_bits / 8.0)
         # Leak/Spike: read + write every neuron's state word once per step
         bytes_per_step = bytes_per_step + 2.0 * cfg.n_out * _layer_state_bytes(cfg)
+        # merge: each extra fan-in core sends an int32 partial current per neuron
+        bytes_per_step = bytes_per_step + 4.0 * (cfg.fan_in_cores - 1) * cfg.n_out
         layer_bytes.append(float(bytes_per_step.sum()))
     return BandwidthProfile(
         layer_bytes_per_image=tuple(layer_bytes),

@@ -1,7 +1,10 @@
 """Multi-core Flexi-NeurA network: layer-to-core mapping and full simulation.
 
 The paper maps each hidden/output layer to a dedicated processing core wired
-through AER packets (Fig. 4).  Functionally the system is a layered SNN
+through AER packets (Fig. 4); a layer wider than one core's 256 addresses or
+neurons maps onto several (``LayerConfig.core_slices``, see
+``repro.core.snn_layer``), so :attr:`NetworkConfig.n_cores` can exceed the
+layer count.  Functionally the system is a layered SNN
 unrolled over time; this module provides
 
 * :func:`init_float_params` / :func:`quantize_params` -- the train->deploy path
@@ -74,6 +77,11 @@ class NetworkConfig:
     @property
     def n_classes(self) -> int:
         return self.layers[-1].n_out
+
+    @property
+    def n_cores(self) -> int:
+        """Physical cores after wide layers are split across cores."""
+        return sum(lc.n_cores for lc in self.layers)
 
     def replace_precisions(self, w_bits=None, w_rec_bits=None, leak_bits=None):
         """A new config with uniformly overridden DSE knobs (None = keep)."""

@@ -22,6 +22,23 @@ provided no intermediate event-by-event accumulation saturates (integration
 is order-dependent only under saturation; ``repro.core.events`` provides the
 strict per-event reference used by property tests to check this contract).
 
+Core mapping: a core takes at most ``CORE_WIDTH`` (256) input addresses
+and holds at most 256 neurons -- the 8-bit ASPL/ASCL addresses of
+``repro.core.events``.  A wider layer maps onto
+``ceil(n_in / 256) x ceil(n_out / 256)`` physical cores
+(:meth:`LayerConfig.core_slices`): each takes one slice of the input
+addresses for one slice of the neurons, and the cores that hold the same
+neurons merge their int32 partial currents before phase A's one saturation.
+int32 addition is associative and saturation is applied once per step
+(``_integrate_acc``), so the split is bit-identical to the unsplit layer:
+the vectorised paths keep computing a split layer as one product.  An ATA-T
+layer's dense recurrence stays inside one core, so its neurons may not span
+more than one.
+
+Device ops carry the layer's parts in their op metadata: the feed-forward
+product runs under ``jax.named_scope(FF_SCOPE)``, the ATA-T recurrent
+product under ``jax.named_scope(RECURRENT_SCOPE)``.
+
 Timing convention: a spike generated in phase B of step ``t`` is the input
 that the next layer integrates at its step ``t`` (cores run pipelined, one
 step apart in wall-clock but aligned in step index), and is this layer's own
@@ -33,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import math
 from typing import NamedTuple
 
 import jax
@@ -43,6 +61,9 @@ from repro.core.coeff_gen import DecayCode
 from repro.core.fixed_point import saturate
 
 __all__ = [
+    "CORE_WIDTH",
+    "FF_SCOPE",
+    "RECURRENT_SCOPE",
     "NeuronModel",
     "ResetMode",
     "Topology",
@@ -61,6 +82,13 @@ __all__ = [
     "float_layer_init",
     "float_layer_step",
 ]
+
+
+#: Input addresses and neurons one physical core holds (8-bit AER addresses).
+CORE_WIDTH = 256
+#: ``jax.named_scope`` names of a layer's two products, read from the device trace.
+FF_SCOPE = "neura.core.ff"
+RECURRENT_SCOPE = "neura.core.recurrent"
 
 
 class NeuronModel(str, enum.Enum):
@@ -82,7 +110,10 @@ class Topology(str, enum.Enum):
 
 @dataclasses.dataclass(frozen=True)
 class LayerConfig:
-    """Design-time parameters of one Flexi-NeurA core (pre-synthesis)."""
+    """Design-time parameters of one logical layer (pre-synthesis).
+
+    A layer wider than one core maps onto several (:meth:`core_slices`).
+    """
 
     n_in: int
     n_out: int
@@ -103,11 +134,11 @@ class LayerConfig:
     def __post_init__(self):
         if self.n_in <= 0 or self.n_out <= 0:
             raise ValueError("layer sizes must be positive")
-        if self.n_out > 256 or self.n_in > 256:
+        if self.topology == Topology.ATA_T and self.n_out > CORE_WIDTH:
             raise ValueError(
-                "a Flexi-NeurA core supports at most 256 neurons per layer "
-                f"(got n_in={self.n_in}, n_out={self.n_out}); split the layer "
-                "across cores or reduce it as the paper does for its datasets"
+                f"an ATA-T layer's dense recurrence must stay inside one core of "
+                f"{CORE_WIDTH} neurons (got n_out={self.n_out}); a wider layer maps "
+                f"onto several cores only with the FF or ATA-F topology"
             )
         for name in ("w_bits", "w_rec_bits"):
             b = getattr(self, name)
@@ -117,6 +148,44 @@ class LayerConfig:
             b = getattr(self, name)
             if not 4 <= b <= 24:
                 raise ValueError(f"{name} must be in [4, 24], got {b}")
+
+    @property
+    def fan_in_cores(self) -> int:
+        """Cores that share this layer's input addresses, per slice of neurons."""
+        return math.ceil(self.n_in / CORE_WIDTH)
+
+    @property
+    def neuron_cores(self) -> int:
+        """Cores that share this layer's neurons."""
+        return math.ceil(self.n_out / CORE_WIDTH)
+
+    @property
+    def n_cores(self) -> int:
+        """Physical cores this layer maps onto."""
+        return self.fan_in_cores * self.neuron_cores
+
+    def core_slices(self) -> list[tuple[range, range, "LayerConfig"]]:
+        """``(input addresses, neurons, core config)`` of each physical core.
+
+        Neuron slice by neuron slice, then input slice by input slice.  The
+        first core of each neuron slice (``addresses.start == 0``) is the
+        state core: it holds the neurons' state, runs the recurrence and
+        phase B.  The others are feed-forward only and send it their partial
+        currents.  A layer that fits one core is its own single slice.
+        """
+        out = []
+        for j in range(0, self.n_out, CORE_WIDTH):
+            for i in range(0, self.n_in, CORE_WIDTH):
+                rows = range(i, min(i + CORE_WIDTH, self.n_in))
+                cols = range(j, min(j + CORE_WIDTH, self.n_out))
+                core = dataclasses.replace(
+                    self,
+                    n_in=len(rows),
+                    n_out=len(cols),
+                    topology=self.topology if i == 0 else Topology.FF,
+                )
+                out.append((rows, cols, core))
+        return out
 
     @property
     def is_recurrent(self) -> bool:
@@ -186,7 +255,8 @@ def _integrate_acc(cfg: LayerConfig, params: IntLayerParams, state: LayerState, 
     """
     acc = ff_acc
     if cfg.topology == Topology.ATA_T:
-        acc = acc + jnp.einsum("bi,io->bo", state.prev_spk, params.w_rec)
+        with jax.named_scope(RECURRENT_SCOPE):
+            acc = acc + jnp.einsum("bi,io->bo", state.prev_spk, params.w_rec)
     elif cfg.topology == Topology.ATA_F:
         acc = acc + state.prev_spk * params.w_rec
     if cfg.neuron == NeuronModel.SYNAPTIC:
@@ -202,8 +272,8 @@ def int_phase_a(cfg: LayerConfig, params: IntLayerParams, state: LayerState, s_i
     deployment arithmetic, per phase so the float mirror can attach at every
     intermediate.
     """
-    s_in_i = s_in.astype(jnp.int32)
-    ff_acc = jnp.einsum("bi,io->bo", s_in_i, params.w_ff)  # {0,1} matmul, int32
+    with jax.named_scope(FF_SCOPE):
+        ff_acc = jnp.einsum("bi,io->bo", s_in.astype(jnp.int32), params.w_ff)  # int32
     return _integrate_acc(cfg, params, state, ff_acc)
 
 

@@ -9,8 +9,12 @@ structural character:
   to <=16x16 = 256 channels) with spatial jitter + pixel noise, rate-coded
   into Bernoulli spike trains.
 * ``shd_like``    -- 20-class synthetic cochleagrams: class-keyed
-  spectro-temporal ridge patterns over 140 channels (700 cochlear channels
-  reduced by k=5, as the paper's 700/k < 256 rule), inherently spike-based.
+  spectro-temporal ridge patterns, inherently spike-based.  140 channels by
+  default: the paper reduces SHD's 700 cochlear channels by k=5 so that one
+  core of 256 addresses takes them (its 700/k < 256 rule).  That is the
+  paper's reduction, not a limit of this code: ``channels=700`` gives the full
+  width, which a layer split over three cores takes
+  (``LayerConfig.core_slices``).
 * ``dvs_like``    -- 11-class moving-edge event streams on a 16x16 grid
   (256 channels after the paper's conv-front-end compression), direction /
   speed encode the class.

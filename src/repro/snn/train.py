@@ -20,6 +20,7 @@ import numpy as np
 from repro.core import backend as backend_lib
 from repro.core import shard as shard_lib
 from repro.core.network import NetworkConfig, init_float_params, run_float, run_int
+from repro.core.snn_layer import Topology
 from repro.data.snn_datasets import SpikeDataset
 from repro.snn import qat as qat_lib
 from repro.snn.surrogate import fast_sigmoid
@@ -308,14 +309,20 @@ def eval_int_population(
     and places it over the devices once (``stack_population_sharded``), so
     a batch's launch moves only that batch's spikes.
 
-    Profiler spans: ``neura.dse.stack`` (arguments ``candidates`` and
+    Profiler spans: ``neura.dse.stack`` (arguments ``candidates``;
+    ``cores``, the network's physical cores after the split of wide layers;
+    ``recurrent_macs``, the sum of ``n_out ** 2`` over ATA-T layers; and
     ``shards``, the devices the population was placed over) around building
     the population, then one ``neura.dse.batch`` per data batch (``index``,
-    ``samples``) holding its ``neura.dse.launch`` and
+    ``samples``, ``steps``) holding its ``neura.dse.launch`` and
     ``neura.dse.readback``; the rest of a batch is the host reduction.
     """
     P = len(candidate_nets)
-    with jax.profiler.TraceAnnotation("neura.dse.stack", candidates=P) as span:
+    # dense recurrent multiply-accumulates per sample and step of one candidate
+    rec_macs = sum(lc.n_out**2 for lc in net.layers if lc.topology == Topology.ATA_T)
+    with jax.profiler.TraceAnnotation(
+        "neura.dse.stack", candidates=P, cores=net.n_cores, recurrent_macs=rec_macs
+    ) as span:
         backend_lib.check_population_structure(net, candidate_nets)
         dmesh = shard_lib.resolve_mesh(mesh)
         stacked, beta_regs, alpha_regs = shard_lib.stack_population_sharded(
@@ -342,7 +349,9 @@ def eval_int_population(
     in_ev = None  # [T]
     for index, (spikes, labels) in enumerate(ds.batches(batch_size)):
         n = len(labels)
-        with jax.profiler.TraceAnnotation("neura.dse.batch", index=index, samples=n):
+        with jax.profiler.TraceAnnotation(
+            "neura.dse.batch", index=index, samples=n, steps=spikes.shape[0]
+        ):
             with jax.profiler.TraceAnnotation("neura.dse.launch"):
                 out = pop_fwd(jnp.asarray(spikes))
             with jax.profiler.TraceAnnotation("neura.dse.readback"):

@@ -195,8 +195,10 @@ def test_population_spans_one_stack_and_one_batch_per_batch(dse_trace):
     stack = [e for e in events if e[0] == "neura.dse.stack"]
     assert len(stack) == 1 and stack[0][3]["candidates"] == 3
     assert stack[0][3]["shards"] == 1
+    assert stack[0][3]["cores"] == 2 and stack[0][3]["recurrent_macs"] == 0  # ATA-F, no ATA-T
     batches = [e for e in events if e[0] == "neura.dse.batch"]
     assert [(b[3]["index"], b[3]["samples"]) for b in batches] == [(0, 16), (1, 16), (2, 8)]
+    assert {b[3]["steps"] for b in batches} == {6}
     assert stack[0][2] <= batches[0][1]
     for b in batches:
         kids = [e[0] for e in _children(events, b)]
