@@ -47,6 +47,7 @@ SPAN_NAMES = (
     "neura.serve.readback",
     "neura.serve.complete",
     "neura.dse.stack",
+    "neura.dse.place",
     "neura.dse.batch",
     "neura.dse.launch",
     "neura.dse.shard_pad",

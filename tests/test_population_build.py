@@ -4,8 +4,11 @@
 registers in one jitted program; it must return exactly what stacking
 each leaf on its own returns. ``stack_population_sharded`` runs the same
 build, pads the candidate axis to the shard count and places it on the
-mesh once, so ``eval_int_population`` over a mesh moves only spikes per
-batch. The mesh cases run on four forced host devices in a fresh
+mesh once. ``eval_int_population`` places the rasters once per call too
+and cuts each batch out of them on the device; its accuracies and event
+statistics must equal serial ``eval_int`` and the per-batch path it
+replaced, which gathered, transposed and uploaded every batch on the
+host. The mesh cases run on four forced host devices in a fresh
 interpreter.
 """
 
@@ -20,9 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import backend
+from repro.core import backend, shard
 from repro.core.network import NetworkConfig, init_float_params, quantize_params
 from repro.core.snn_layer import IntLayerParams, LayerConfig, NeuronModel, Topology
+from repro.data.snn_datasets import mnist_like
+from repro.snn import train
 
 PRECISIONS = [(b, r, l) for b in (4, 6, 8, 12) for r in (4, 8) for l in (3, 8)] * 2
 
@@ -80,16 +85,78 @@ def test_one_program_build_equals_per_leaf_stack(topology, n):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+def _dataset():
+    """20 rasters over the nets' 48 inputs: batches of 8 leave a ragged 4."""
+    ds = mnist_like(n=20, T=4, seed=5)
+    return type(ds)(ds.spikes[..., :48], ds.labels, ds.n_classes, ds.name)
+
+
+def _per_batch_sweep(net, cands, qps, ds, batch_size, mesh=None):
+    """The sweep as it was: each batch gathered, transposed and uploaded on the host.
+
+    Returns accuracies ``[P]``, layer events ``[P, T, L]`` and input events ``[T]``.
+    """
+    dmesh = shard.resolve_mesh(mesh)
+    stacked, beta, alpha = shard.stack_population_sharded(cands, qps, dmesh)
+    n_cand = len(cands)
+    correct, total, layer_ev, in_ev = np.zeros(n_cand, np.int64), 0, 0.0, 0.0
+    for spikes, labels in ds.batches(batch_size):
+        spikes = jnp.asarray(spikes)
+        if dmesh is None:
+            out = train._population_fwd(net, stacked, beta, alpha, spikes)
+        else:
+            counts, emitted = shard.run_int_population_sharded(
+                net, stacked, beta, alpha, spikes, dmesh, return_events=True
+            )
+            iev = jnp.mean(jnp.sum(spikes != 0, axis=-1), axis=-1)
+            out = jnp.argmax(counts, axis=-1), jnp.mean(emitted, axis=-1), iev
+        preds, evs, iev = (np.asarray(a) for a in out)
+        n = len(labels)
+        correct += (preds[:n_cand] == labels[None, :]).sum(axis=1)
+        total += n
+        layer_ev, in_ev = layer_ev + evs[:n_cand] * n, in_ev + iev * n
+    return correct / total, layer_ev / total, in_ev / total
+
+
+def _same_as_serial_and_per_batch(net, cands, qps, ds, batch_size, mesh=None):
+    """Per-candidate equality of the placed sweep with serial ``eval_int`` and the old path."""
+    accs, stats = train.eval_int_population(
+        net, cands, qps, ds, batch_size=batch_size, return_stats=True, mesh=mesh
+    )
+    old_accs, old_layer, old_in = _per_batch_sweep(net, cands, qps, ds, batch_size, mesh)
+    np.testing.assert_array_equal(accs, old_accs)
+    for j, (c, q) in enumerate(zip(cands, qps)):
+        acc, st = train.eval_int(c, q, ds, batch_size=batch_size, return_stats=True)
+        assert accs[j] == acc
+        got = np.stack(stats[j]["layer_events_per_step"], axis=1)  # [T, L]
+        np.testing.assert_array_equal(got, old_layer[j])
+        np.testing.assert_array_equal(got, np.stack(st["layer_events_per_step"], axis=1))
+        np.testing.assert_array_equal(stats[j]["input_events_per_step"], old_in)
+        np.testing.assert_array_equal(stats[j]["input_events_per_step"], st["input_events_per_step"])
+    return accs
+
+
+@pytest.mark.parametrize("topology", [Topology.FF, Topology.ATA_F, Topology.ATA_T])
+def test_placed_sweep_with_a_ragged_batch_equals_serial_and_per_batch(topology):
+    cands, qps = _population(topology, 5)
+    accs = _same_as_serial_and_per_batch(cands[0], cands, qps, _dataset(), batch_size=8)
+    assert len(accs) == 5
+
+
+def test_one_batch_larger_than_the_set_takes_the_whole_set():
+    cands, qps = _population(Topology.ATA_F, 3)
+    _same_as_serial_and_per_batch(cands[0], cands, qps, _dataset(), batch_size=64)
+
+
 _MESH_PROG = """
 import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, {tests!r})
 import jax, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from test_population_build import _population
+from test_population_build import _dataset, _population, _same_as_serial_and_per_batch
 from repro.core import shard
 from repro.core.snn_layer import Topology
-from repro.data.snn_datasets import mnist_like
 from repro.snn.train import eval_int, eval_int_population
 
 assert len(jax.devices()) == 4
@@ -102,11 +169,13 @@ placed = [
     for a in jax.tree.leaves((stacked, beta, alpha))
 ]
 tail = [bool((np.asarray(a)[5:] == np.asarray(a)[4]).all()) for a in jax.tree.leaves(stacked)]
-ds = mnist_like(n=20, T=4, seed=5)
-ds = type(ds)(ds.spikes[..., :48], ds.labels, ds.n_classes, ds.name)
+ds = _dataset()
 pop = eval_int_population(cands[0], cands, qps, ds, batch_size=8, mesh=4)
 serial = [eval_int(c, q, ds, batch_size=8) for c, q in zip(cands, qps)]
-print(json.dumps({{"placed": placed, "tail": tail, "pop": list(pop), "serial": serial}}))
+# raises where the placed sweep's stats differ from serial or the per-batch path
+_same_as_serial_and_per_batch(cands[0], cands, qps, ds, batch_size=8, mesh=4)
+print(json.dumps({{"placed": placed, "tail": tail, "pop": list(pop), "serial": serial,
+                  "stats_equal": True}}))
 """
 
 
@@ -138,3 +207,8 @@ def test_sharded_build_is_padded_and_placed_on_the_candidate_axis(mesh_result):
 def test_ragged_population_on_four_devices_scores_as_serial(mesh_result):
     assert len(mesh_result["pop"]) == 5
     assert mesh_result["pop"] == mesh_result["serial"]
+
+
+def test_ragged_population_on_four_devices_stats_equal_serial_and_per_batch(mesh_result):
+    # five candidates over four devices, 20 rasters in batches of 8: both axes ragged
+    assert mesh_result["stats_equal"]

@@ -205,6 +205,26 @@ def test_population_spans_one_stack_and_one_batch_per_batch(dse_trace):
         assert kids == ["neura.dse.launch", "neura.dse.readback"]
 
 
+def _place_before_batches(events):
+    """The one ``neura.dse.place`` of a traced call, checked to lie between stack and batches."""
+    (stack,) = [e for e in events if e[0] == "neura.dse.stack"]
+    (place,) = [e for e in events if e[0] == "neura.dse.place"]
+    batches = [e for e in events if e[0] == "neura.dse.batch"]
+    assert stack[2] <= place[1] and place[2] <= batches[0][1]
+    for b in batches:  # on a mesh the launch holds the (empty) padding span too
+        kids = [e[0] for e in _children(events, b) if e[0] != "neura.dse.shard_pad"]
+        assert kids == ["neura.dse.launch", "neura.dse.readback"]
+    return place
+
+
+def test_population_places_the_rasters_once_between_stack_and_batches(dse_trace):
+    (_, events), _ = dse_trace
+    place = _place_before_batches(events)
+    n, T, C = _dse_case()[3].spikes.shape
+    assert place[3]["bytes"] == n * T * C == 40 * 6 * 256  # uint8, in the stored layout
+    assert place[3]["samples"] == n
+
+
 def test_population_outputs_identical_with_the_profiler_on(dse_trace):
     ((accs_on, stats_on), _), sweep = dse_trace
     accs_off, stats_off = sweep()
@@ -255,6 +275,11 @@ def test_mesh_padding_is_a_child_of_the_launch(mesh_events):
         assert launch[1] <= pad[1] and pad[2] <= launch[2]
 
 
+def test_mesh_places_the_rasters_once_with_the_bytes_of_one_device(mesh_events):
+    place = _place_before_batches(mesh_events)
+    assert place[3]["bytes"] == 40 * 6 * 256 and place[3]["samples"] == 40
+
+
 def test_stack_span_counts_the_devices_the_population_was_placed_over(mesh_events):
     (stack,) = [e for e in mesh_events if e[0] == "neura.dse.stack"]
     assert stack[3]["candidates"] == 3 and stack[3]["shards"] == 4
@@ -266,7 +291,7 @@ def test_every_span_name_is_emitted_and_none_is_a_harness_name(
     (_, serve_events), ((_, dse_events), _) = serve_trace, dse_trace
     seen = {e[0] for e in serve_events + dse_events + mesh_events}
     assert seen == set(SPAN_NAMES)
-    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 11
+    assert len(SPAN_NAMES) == len(set(SPAN_NAMES)) == 12
     for name in SPAN_NAMES:
         assert name.startswith("neura.") and not name.startswith("bench.")
         assert name not in HARNESS_SPANS
