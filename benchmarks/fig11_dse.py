@@ -16,8 +16,8 @@ import csv
 import pathlib
 import time
 
-from repro.core.flexplorer import annealer as annealer_lib
 from repro.core.flexplorer import cost as cost_lib
+from repro.core.flexplorer import strategies as strategies_lib
 from repro.core.flexplorer.explorer import EvalSpec, SearchSpec, SNNSearchSpace, explore_snn
 from repro.core.network import NetworkConfig
 from repro.core.snn_layer import LayerConfig, NeuronModel, Topology
@@ -48,13 +48,13 @@ def run(epochs: int = 5, T: int = 20, backend: str = "reference", population: in
         search=SearchSpec(
             space=SNNSearchSpace(ff_bits=(4, 8, 12, 16), rec_bits=(4, 8, 12, 16), leak_bits=(3, 8)),
             weights=weights,
-            config=annealer_lib.AnnealConfig(t_start=1.0, t_min=0.02, alpha=0.6, eval_divisor=3, seed=0),
+            config=strategies_lib.AnnealConfig(t_start=1.0, t_min=0.02, alpha=0.6, eval_divisor=3, seed=0),
             population=population,
         ),
         evaluate=EvalSpec(backend=backend),
     )
     # figure data: every evaluated candidate, sorted by total cost
-    rows = sorted(result.anneal.trace, key=lambda r: r["total"])
+    rows = sorted(result.search.trace, key=lambda r: r["total"])
     OUT.parent.mkdir(parents=True, exist_ok=True)
     with OUT.open("w", newline="") as f:
         w = csv.writer(f)
@@ -62,11 +62,11 @@ def run(epochs: int = 5, T: int = 20, backend: str = "reference", population: in
         for r in rows:
             w.writerow([r["cfg"].get("ff_bits"), r["cfg"].get("rec_bits"), r["cfg"].get("leak_bits"),
                         f"{r['total']:.5f}", f"{r['hw']:.5f}", f"{r['acc_cost']:.5f}", f"{r['accuracy']:.4f}"])
-    chosen = result.anneal.best_breakdown
+    chosen = result.search.best_breakdown
     us = (time.time() - t0) * 1e6
     derived = (
         f"chosen_ff={chosen['ff_bits']};rec={chosen.get('rec_bits')};leak={chosen['leak_bits']}"
-        f";acc={chosen['accuracy']:.4f};evals={result.anneal.evaluations}"
+        f";acc={chosen['accuracy']:.4f};evals={result.search.evaluations}"
         f";paper_choice=ff8_rec8_leak8;csv={OUT.name}"
     )
     return [("fig11/dse-ataf-dvs", us, derived)]

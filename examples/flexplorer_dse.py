@@ -5,7 +5,7 @@
 The paper's full flow (Fig. 10) plus this repo's train-in-the-loop second
 phase: the Learning stage trains an ATA-F LIF network on the DVS stand-in;
 the Explorer anneals (ff bits, rec bits, leak precision) against the
-weighted LUT/FF/BRAM + bit-exact-accuracy cost; ``refine_top_k`` then
+weighted LUT/FF/BRAM + bit-exact-accuracy cost; ``RefineSpec(top_k=2)`` then
 QAT-fine-tunes the two best finalists at their own precisions (epoch 0 is
 post-training quantization, so refinement never loses accuracy on the
 scoring set); the "RTL Configurator" stage emits the deployment package our
@@ -20,8 +20,8 @@ import pathlib
 import numpy as np
 
 from repro.core import hw_model
-from repro.core.flexplorer import annealer as annealer_lib
 from repro.core.flexplorer import cost as cost_lib
+from repro.core.flexplorer import strategies as strategies_lib
 from repro.core.flexplorer.explorer import RefineSpec, SearchSpec, SNNSearchSpace, explore_snn
 from repro.core.network import NetworkConfig
 from repro.core.snn_layer import LayerConfig, NeuronModel, Topology
@@ -61,7 +61,7 @@ def main():
         search=SearchSpec(
             space=SNNSearchSpace(ff_bits=(3, 4, 6, 8), rec_bits=(3, 4, 6, 8), leak_bits=(3, 8)),
             weights=cost_lib.CostWeights(c_hw=0.5, c_acc=0.5, c_lut=0.33, c_ff=0.33, c_bram=0.34),
-            config=annealer_lib.AnnealConfig(t_start=1.0, t_min=0.05, alpha=0.6, eval_divisor=3, seed=0),
+            config=strategies_lib.AnnealConfig(t_start=1.0, t_min=0.05, alpha=0.6, eval_divisor=3, seed=0),
         ),
         refine=RefineSpec(top_k=2, train_ds=train, epochs=3, lr=1.5e-3),
     )
@@ -75,7 +75,7 @@ def main():
     # deploy the best refined candidate when one beats the annealer's pick
     # at no higher total cost; the PTQ incumbent otherwise
     best_refined = min(result.refined, key=lambda r: r.total_cost, default=None)
-    if best_refined is not None and best_refined.total_cost <= result.anneal.best_cost:
+    if best_refined is not None and best_refined.total_cost <= result.search.best_cost:
         deploy_net, deploy_qparams = best_refined.net, best_refined.qparams
         print(f"deploying refined candidate {best_refined.breakdown}")
     else:

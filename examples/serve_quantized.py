@@ -46,8 +46,7 @@ def main():
     print(
         f"\ngreedy outputs identical under int8/int4: {agree}/{len(prompts)} "
         "(random-init weights give near-uniform logits, so argmax is "
-        "quantization-sensitive here; trained-weight fidelity is what "
-        "benchmarks/lm_dse.py scores, and the int8-KV path is "
+        "quantization-sensitive here; the int8-KV path is "
         "greedy-preserving in tests/test_precision_paths.py)"
     )
 

@@ -520,8 +520,7 @@ class EventBackend(InferenceBackend):
         Host-side CSR x dense product through scipy's C kernel: O(nnz *
         n_out) work.  On CPU, XLA's gather/scatter lower to code that loses
         to its own dense matmul even at 5% density, so this is the strategy
-        that actually realises the event-driven win there (the benchmark in
-        ``benchmarks/event_bench.py`` holds it to that).  Host-side by
+        that actually realises the event-driven win there.  Host-side by
         construction: ``jit_compatible = False``, raises under tracing.
 
     ``"pallas"``

@@ -28,11 +28,6 @@ all-gathered), which is what lets NSGA-II populations in the thousands
 score at fleet scale.  Serial mode pays a fresh jit trace per candidate
 configuration; population mode is the DSE wall-clock lever.
 
-The legacy 15-kwarg signature (``space=``, ``anneal_cfg=``, ``eval_batch=``,
-``refine_top_k=``, ...) still works through a deprecation shim that warns
-once per process and maps onto the specs; see ``docs/EXPLORER.md`` for the
-migration table.
-
 The result carries everything the RTL Configurator stage would consume:
 the chosen design-time parameters, quantized weight tables, and the cost
 trace for the Fig.-11-style plot.
@@ -49,7 +44,6 @@ import numpy as np
 from repro.core import backend as backend_lib
 from repro.core import hw_model
 from repro.core import shard as shard_lib
-from repro.core.flexplorer import annealer as annealer_lib
 from repro.core.flexplorer import cost as cost_lib
 from repro.core.flexplorer import strategies as strategies_lib
 from repro.core.network import NetworkConfig, quantize_params
@@ -188,18 +182,6 @@ class ExplorationResult:
     # to the refined front explicitly.
     refined: list[RefinedCandidate] = dataclasses.field(default_factory=list)
 
-    # ``anneal`` was the historical name of the search-result field; keep it
-    # as an alias (both directions, so artifacts pickled before the rename
-    # still expose ``.search``).
-    @property
-    def anneal(self) -> strategies_lib.SearchResult:
-        return self.__dict__.get("search") or self.__dict__["anneal"]
-
-    def __getattr__(self, name):
-        if name == "search" and "anneal" in self.__dict__:
-            return self.__dict__["anneal"]
-        raise AttributeError(name)
-
     def _explored_points(self) -> list[dict]:
         return [
             {"cfg": t["cfg"], "hw_cost": t["hw"], "accuracy": t["accuracy"], "refined": False}
@@ -250,60 +232,6 @@ class ExplorationResult:
         return out
 
 
-# --------------------------------------------------------------------------
-# Legacy kwargs -> spec fields (deprecation shim)
-# --------------------------------------------------------------------------
-
-_LEGACY_KWARGS = {
-    "space": ("search", "space"),
-    "weights": ("search", "weights"),
-    "device": ("search", "device"),
-    "anneal_cfg": ("search", "config"),
-    "population": ("search", "population"),
-    "eval_batch": ("evaluate", "batch"),
-    "backend": ("evaluate", "backend"),
-    "mesh": ("evaluate", "mesh"),
-    "perf_targets": ("evaluate", "perf_targets"),
-    "refine_top_k": ("refine", "top_k"),
-    "refine_train_ds": ("refine", "train_ds"),
-    "refine_epochs": ("refine", "epochs"),
-    "refine_batch": ("refine", "batch"),
-    "refine_lr": ("refine", "lr"),
-}
-
-_LEGACY_WARNED = False
-
-
-def _apply_legacy_kwargs(search, evaluate, refine, legacy: dict):
-    global _LEGACY_WARNED
-    unknown = set(legacy) - set(_LEGACY_KWARGS)
-    if unknown:
-        raise TypeError(f"explore_snn() got unexpected keyword arguments {sorted(unknown)}")
-    if not _LEGACY_WARNED:
-        mapped = ", ".join(
-            f"{k}= -> {grp}.{field}" for k, (grp, field) in sorted(_LEGACY_KWARGS.items()) if k in legacy
-        )
-        warnings.warn(
-            "explore_snn: flat keyword arguments are deprecated; pass "
-            "SearchSpec/EvalSpec/RefineSpec instead (" + mapped + "; see "
-            "docs/EXPLORER.md for the migration table)",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-        _LEGACY_WARNED = True
-    provided = {"search": search, "evaluate": evaluate, "refine": refine}
-    groups = {"search": search or SearchSpec(), "evaluate": evaluate or EvalSpec(), "refine": refine or RefineSpec()}
-    for key, value in legacy.items():
-        grp, field = _LEGACY_KWARGS[key]
-        if provided[grp] is not None:
-            raise TypeError(
-                f"explore_snn() got both {grp}= and legacy {key}=; move {key} "
-                f"into the {type(provided[grp]).__name__}"
-            )
-        groups[grp] = dataclasses.replace(groups[grp], **{field: value})
-    return groups["search"], groups["evaluate"], groups["refine"]
-
-
 def _next_pow2(n: int) -> int:
     p = 1
     while p < n:
@@ -318,7 +246,6 @@ def explore_snn(
     search: SearchSpec | None = None,
     evaluate: EvalSpec | None = None,
     refine: RefineSpec | None = None,
-    **legacy,
 ) -> ExplorationResult:
     """Search precision knobs for a trained SNN (the paper's Explorer stage).
 
@@ -365,13 +292,7 @@ def explore_snn(
     sweep -- then re-scored with the bit-exact quantized evaluator.
     Results land in ``result.refined``; ``best_net``/``best_qparams``
     remain the unrefined incumbent.
-
-    Legacy flat kwargs (``space=``, ``anneal_cfg=``, ``population=``,
-    ``eval_batch=``, ``refine_top_k=``, ...) are accepted through a shim
-    that warns once per process; see ``docs/EXPLORER.md``.
     """
-    if legacy:
-        search, evaluate, refine = _apply_legacy_kwargs(search, evaluate, refine, legacy)
     search = search or SearchSpec()
     evaluate = evaluate or EvalSpec()
     refine = refine or RefineSpec()
@@ -380,10 +301,9 @@ def explore_snn(
 
     if refine.top_k > 0 and refine.train_ds is None:
         raise ValueError(
-            "explore_snn: refine.top_k > 0 needs refine.train_ds (legacy "
-            "kwarg refine_train_ds) -- the data the finalists are "
-            "QAT-fine-tuned on; typically the training split the float "
-            "parameters came from"
+            "explore_snn: refine.top_k > 0 needs RefineSpec.train_ds -- the "
+            "data the finalists are QAT-fine-tuned on; typically the training "
+            "split the float parameters came from"
         )
 
     any_recurrent = any(lc.is_recurrent for lc in net.layers)
@@ -656,7 +576,7 @@ def _gather_population(accs, stats, padded, n_hosts, stats_stash) -> np.ndarray:
     return np.asarray(accs)
 
 
-def _select_finalists(result: annealer_lib.AnnealResult, top_k: int) -> list[tuple]:
+def _select_finalists(result: strategies_lib.SearchResult, top_k: int) -> list[tuple]:
     """The refinement shortlist: Pareto-front members first, then by cost.
 
     Front members are where extra accuracy moves the achievable trade-off

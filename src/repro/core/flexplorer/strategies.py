@@ -22,9 +22,7 @@ cache/trace, pre-computes every candidate's hardware cost (the paper's
 lines 8-13), scores fresh proposals through a caller-supplied batch
 evaluator, snapshots after every ``snapshot_every`` rounds, and returns a
 :class:`SearchResult` -- the uniform result schema (trace / cache / front /
-evaluations) shared by every strategy.  ``AnnealResult`` is kept as an
-alias in ``repro.core.flexplorer.annealer`` so artifacts and imports from
-earlier PRs keep working.
+evaluations) shared by every strategy.
 
 Determinism contract: a strategy draws from its own seeded
 ``numpy.random.Generator`` in a fixed order, and evaluation is pure in the
@@ -159,7 +157,7 @@ class EvalRecord(tuple):
 
 @dataclasses.dataclass
 class SearchResult:
-    """Strategy-agnostic search outcome (the historical ``AnnealResult`` shape).
+    """Strategy-agnostic search outcome.
 
     ``cache`` maps cfg -> :class:`EvalRecord` (indexes like the legacy
     5-tuple); ``trace`` lists every scored candidate in evaluation order;
@@ -313,8 +311,7 @@ class SearchStrategy:
 
 
 # ---------------------------------------------------------------------------
-# Simulated annealing (paper Listing 1), serial -- exact port of the
-# historical ``simulated_annealing`` loop onto the protocol
+# Simulated annealing (paper Listing 1), serial
 # ---------------------------------------------------------------------------
 
 
@@ -330,11 +327,8 @@ class AnnealConfig:
 class AnnealStrategy(SearchStrategy):
     """Serial Metropolis annealer: one neighbour proposal per round.
 
-    The RNG draw order is identical to the historical closed-loop
-    implementation (neighbour draw in ``propose``, acceptance draw in
-    ``observe`` only when the move is uphill), so a search driven through
-    the protocol follows the exact trajectory the legacy
-    ``simulated_annealing`` function produced.
+    RNG draw order: the neighbour draw in ``propose``, the acceptance draw
+    in ``observe`` only when the move is uphill.
     """
 
     name = "anneal"
@@ -408,8 +402,7 @@ class AnnealStrategy(SearchStrategy):
 
 
 # ---------------------------------------------------------------------------
-# Population-parallel annealing with speculative lane fill -- exact port of
-# the historical ``simulated_annealing_population`` loop onto the protocol
+# Population-parallel annealing with speculative lane fill
 # ---------------------------------------------------------------------------
 
 
@@ -421,10 +414,9 @@ class PopulationAnnealStrategy(SearchStrategy):
     candidates per device whatever the batch holds, so the explorer widens
     the fill to the device (x host) multiple and spare lanes score fresh
     candidates instead of shard padding.  The per-temperature proposal
-    budget exactly matches the serial annealer, and the RNG draw order
-    matches the legacy closed-loop implementation (walker/neighbour draws
-    at round boundaries, fill permutation inside ``propose``, acceptance
-    draws in ``observe``).
+    budget exactly matches the serial annealer.  RNG draw order:
+    walker/neighbour draws at round boundaries, the fill permutation inside
+    ``propose``, acceptance draws in ``observe``.
     """
 
     name = "anneal"
@@ -814,7 +806,7 @@ def run_search(
     vmapped ``eval_int_population`` sweep, or a serial per-candidate
     evaluator for width-1 strategies); ``extra_cost_fn``/``metrics_fn``
     add the event-aware perf cost and its extended metrics, evaluated
-    after the accuracy term like the legacy annealer did;
+    after the accuracy term (they read the traffic it measured);
     ``objectives_fn(cfg, record)`` supplies the multi-objective vector
     (default: ``(1 - accuracy, hw_cost)``).
 

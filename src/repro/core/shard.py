@@ -37,9 +37,10 @@ Remainders and fallback rules:
 keyword takes: ``None`` (serial), an ``int`` device count, ``"auto"`` (all
 local devices), a :class:`DeviceMesh`, or a raw 1-D ``jax.sharding.Mesh``.
 
-The measured scaling story lives in ``benchmarks/shard_bench.py`` /
-``BENCH_shard.json``; the design rules (axis choices, donation, fallback)
-are documented in ``docs/ARCHITECTURE.md``.
+Four-chip scaling of the population sweep is measured by the
+``dvs-dse-sweep-4chip`` benchmark cell (``PERF.md``); the design rules
+(axis choices, donation, fallback) are documented in
+``docs/ARCHITECTURE.md``.
 """
 
 from __future__ import annotations

@@ -16,8 +16,8 @@ Terms (seconds, per the assignment's hardware constants for TPU v5e):
     memory     = bytes_per_device / 819e9            (HBM bw per chip)
     collective = wire_bytes_per_device / 50e9        (per-link ICI bw)
 
-cost_analysis numbers were verified to be per-device under SPMD
-(see EXPERIMENTS.md section Dry-run), so no chips factor is needed.
+cost_analysis numbers are per-device under SPMD, so no chips factor is
+needed.
 """
 
 from __future__ import annotations

@@ -14,8 +14,7 @@ Exposed as a shard_map-compatible primitive; numerically identical to the
 gathered matmul (property-tested).  On the dry-run meshes it trades the
 all-gather's (g-1)/g·|W| wire for the same bytes on ppermute edges, but in
 g-1 *overlappable* hops -- the win is schedule, not bytes, so it shows up
-in wall-clock (TPU) rather than the wire-byte roofline term; recorded in
-EXPERIMENTS.md §Perf as the gemma2-train lever.
+in wall-clock (TPU) rather than the wire-byte roofline term.
 """
 
 from __future__ import annotations

@@ -17,8 +17,7 @@ mesh shardings, compiles it, and records:
                           collective-permute), ring-cost accounted
   * derived roofline terms (seconds) + dominant bottleneck
 
-Results land in ``experiments/dryrun/<arch>__<shape>__<mesh>.json``;
-benchmarks/roofline.py renders the EXPERIMENTS.md tables from them.
+Results land in ``experiments/dryrun/<arch>__<shape>__<mesh>.json``.
 
 Usage:
   python -m repro.launch.dryrun --arch mamba2-780m --shape train_4k --mesh single

@@ -105,7 +105,7 @@ def _serve_params(arch, mesh, cfg, quant, serve_optimized: bool):
     inherits.  ``serve_optimized``: bf16 weights sharded TP-only (replicated
     over data) -- batch-sharded decode then needs *zero* parameter
     collectives per step, removing the all-gather wall the baseline dry-run
-    measures (EXPERIMENTS.md section Perf).
+    measures.
     """
     abs_params = arch.abstract_params(cfg)
     p_specs = arch.param_pspecs(mesh, cfg)

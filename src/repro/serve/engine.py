@@ -8,9 +8,8 @@ free their slots immediately (continuous batching -- no head-of-line
 blocking on long generations).
 
 Weights can be served quantized through the paper's precision machinery
-(``PrecisionPolicy``), which is how the LM decode memory roofline is
-driven down -- measured in ``EXPERIMENTS.md#perf`` ("LM decode memory
-roofline" bullet).  The SNN-side counterpart -- the paper's actual
+(``PrecisionPolicy``), which shrinks the weight bytes each decode step
+reads.  The SNN-side counterpart -- the paper's actual
 workload served the same continuous-batching way -- is
 ``repro.serve.snn_engine``; both engines are documented side by side in
 ``docs/SERVING.md``.

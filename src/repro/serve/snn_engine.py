@@ -66,10 +66,9 @@ The **front-line control plane** (``repro.serve.scheduler`` +
 requests become visible at ``arrival_s`` offsets); ``submit``/``tick``
 expose the loop for callers that drive it themselves; and
 :class:`AsyncSNNServer` is an asyncio facade whose ``submit`` resolves a
-future on completion.  Throughput/latency vs serial ``run_int`` is measured
-by ``benchmarks/serve_bench.py`` (``BENCH_serve.json``), multi-device lane
-sharding by ``benchmarks/shard_bench.py`` (``BENCH_shard.json``); the
-serving story is documented in ``docs/SERVING.md``.
+future on completion.  Throughput and p95 latency on the chip are measured
+by the ``*-serve-open`` benchmark cells (``PERF.md``); the serving story is
+documented in ``docs/SERVING.md``.
 """
 
 from __future__ import annotations
@@ -1266,8 +1265,8 @@ class SNNServeEngine:
         Requests become visible when the wall clock passes their
         ``arrival_s`` offset from the call's start (an arrival process, not
         a closed loop): per-request ``latency_s`` therefore includes
-        queueing delay, which is what the offered-load sweep in
-        ``benchmarks/serve_bench.py`` reports p50/p99 over.  When the engine
+        queueing delay, which is what the open-loop serve benchmark
+        reports its p95 over.  When the engine
         is idle and the next arrival is in the future it sleeps until then.
         """
         pending = sorted(requests, key=lambda r: r.arrival_s)

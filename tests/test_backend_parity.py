@@ -220,7 +220,7 @@ def test_traced_decay_matches_static():
 
 def test_explore_snn_population_mode_agrees_with_serial():
     """Population DSE scores every config it shares with serial identically."""
-    from repro.core.flexplorer import annealer as annealer_lib
+    from repro.core.flexplorer import strategies as strategies_lib
     from repro.core.flexplorer.explorer import EvalSpec, SearchSpec, SNNSearchSpace, explore_snn
 
     net = _make_net(32, 16, 4, 6, NeuronModel.LIF, ResetMode.SUBTRACT)
@@ -229,18 +229,18 @@ def test_explore_snn_population_mode_agrees_with_serial():
     ds.spikes = ds.spikes[:, :, : net.n_in]
     ds.labels = ds.labels % 4
     space = SNNSearchSpace(ff_bits=(4, 6, 8), leak_bits=(3, 8))
-    cfg = annealer_lib.AnnealConfig(t_start=1.0, t_min=0.2, alpha=0.5, seed=0)
+    cfg = strategies_lib.AnnealConfig(t_start=1.0, t_min=0.2, alpha=0.5, seed=0)
     ev = EvalSpec(batch=32)
     serial = explore_snn(net, params, ds, search=SearchSpec(space=space, config=cfg), evaluate=ev)
     pop = explore_snn(
         net, params, ds, search=SearchSpec(space=space, config=cfg, population=4), evaluate=ev
     )
-    shared = serial.anneal.cache.keys() & pop.anneal.cache.keys()
+    shared = serial.search.cache.keys() & pop.search.cache.keys()
     assert shared  # both searches touched overlapping candidates
     for c in shared:
-        assert serial.anneal.cache[c][3] == pop.anneal.cache[c][3]  # accuracy
-    assert pop.anneal.best in pop.anneal.cache
-    assert 0.0 <= pop.anneal.best_breakdown["accuracy"] <= 1.0
+        assert serial.search.cache[c][3] == pop.search.cache[c][3]  # accuracy
+    assert pop.search.best in pop.search.cache
+    assert 0.0 <= pop.search.best_breakdown["accuracy"] <= 1.0
 
 
 def test_backend_registry():
@@ -368,8 +368,8 @@ def test_record_event_stats_shapes():
 def test_explore_snn_event_aware_perf_cost():
     """c_perf > 0 adds the event-driven latency/energy term; serial and
     population modes score shared candidates identically on acc AND perf."""
-    from repro.core.flexplorer import annealer as annealer_lib
     from repro.core.flexplorer import cost as cost_lib
+    from repro.core.flexplorer import strategies as strategies_lib
     from repro.core.flexplorer.explorer import EvalSpec, SearchSpec, SNNSearchSpace, explore_snn
 
     net = _make_net(32, 16, 4, 6, NeuronModel.LIF, ResetMode.SUBTRACT)
@@ -378,7 +378,7 @@ def test_explore_snn_event_aware_perf_cost():
     ds.spikes = ds.spikes[:, :, : net.n_in]
     ds.labels = ds.labels % 4
     space = SNNSearchSpace(ff_bits=(4, 6, 8), leak_bits=(3, 8))
-    cfg = annealer_lib.AnnealConfig(t_start=1.0, t_min=0.2, alpha=0.5, seed=0)
+    cfg = strategies_lib.AnnealConfig(t_start=1.0, t_min=0.2, alpha=0.5, seed=0)
     w = cost_lib.CostWeights(c_hw=0.4, c_acc=0.4, c_perf=0.2)
     ev = EvalSpec(batch=32)
     serial = explore_snn(
@@ -388,9 +388,9 @@ def test_explore_snn_event_aware_perf_cost():
         net, params, ds,
         search=SearchSpec(space=space, config=cfg, weights=w, population=4), evaluate=ev,
     )
-    assert serial.anneal.best_breakdown["perf_cost"] > 0
-    shared = serial.anneal.cache.keys() & pop.anneal.cache.keys()
+    assert serial.search.best_breakdown["perf_cost"] > 0
+    shared = serial.search.cache.keys() & pop.search.cache.keys()
     assert shared
     for c in shared:
-        assert serial.anneal.cache[c][3] == pop.anneal.cache[c][3]  # accuracy
-        assert serial.anneal.cache[c][4] == pytest.approx(pop.anneal.cache[c][4])  # perf
+        assert serial.search.cache[c][3] == pop.search.cache[c][3]  # accuracy
+        assert serial.search.cache[c][4] == pytest.approx(pop.search.cache[c][4])  # perf

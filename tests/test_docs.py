@@ -27,4 +27,4 @@ def test_markdown_relative_links_resolve():
 def test_checker_covers_the_core_docs():
     mod = _load_checker()
     names = {p.name for p in mod._doc_files()}
-    assert {"README.md", "EXPERIMENTS.md", "ARCHITECTURE.md", "SERVING.md"} <= names
+    assert {"README.md", "PERF.md", "ARCHITECTURE.md", "SERVING.md"} <= names

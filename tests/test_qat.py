@@ -190,5 +190,5 @@ def test_explore_snn_refine_requires_train_ds(tiny_trained):
     from repro.core.flexplorer.explorer import RefineSpec, explore_snn
 
     net, result, train, test = tiny_trained
-    with pytest.raises(ValueError, match="refine_train_ds"):
+    with pytest.raises(ValueError, match=r"RefineSpec\.train_ds"):
         explore_snn(net, result.params, test, refine=RefineSpec(top_k=1))

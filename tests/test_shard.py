@@ -265,21 +265,21 @@ def test_eval_int_population_mesh_matches_serial(n_cands):
 
 
 def test_explore_snn_mesh_scores_match():
-    from repro.core.flexplorer import annealer as annealer_lib
+    from repro.core.flexplorer import strategies as strategies_lib
     from repro.core.flexplorer.explorer import EvalSpec, SearchSpec, SNNSearchSpace, explore_snn
 
     net = _make_net()
     params, _ = _quantized(net)
     ds = mnist_like(n=48, T=6, seed=6)
     space = SNNSearchSpace(ff_bits=(4, 6, 8), leak_bits=(3, 8))
-    cfg = annealer_lib.AnnealConfig(t_start=1.0, t_min=0.3, alpha=0.5, seed=0)
+    cfg = strategies_lib.AnnealConfig(t_start=1.0, t_min=0.3, alpha=0.5, seed=0)
     spec = SearchSpec(space=space, config=cfg, population=4)
     plain = explore_snn(net, params, ds, search=spec, evaluate=EvalSpec(batch=24))
     meshed = explore_snn(net, params, ds, search=spec, evaluate=EvalSpec(batch=24, mesh="auto"))
-    shared = plain.anneal.cache.keys() & meshed.anneal.cache.keys()
+    shared = plain.search.cache.keys() & meshed.search.cache.keys()
     assert shared
     for c in shared:
-        assert plain.anneal.cache[c][3] == meshed.anneal.cache[c][3]  # accuracy
+        assert plain.search.cache[c][3] == meshed.search.cache[c][3]  # accuracy
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """Determinism + interface contracts for the synthetic dataset generators.
 
 The three generators are the reproduction's stand-ins for MNIST / SHD /
-DVS-Gesture; everything downstream (training, DSE scoring, benchmarks,
-committed BENCH_* trajectories) assumes they are bit-reproducible from their
+DVS-Gesture; everything downstream (training, DSE scoring, the paper
+scripts and the benchmark) assumes they are bit-reproducible from their
 seed and expose the documented raster interface.  These tests pin that
 contract, plus the ``batches`` iteration rules (full coverage including the
 ragged tail batch -- silently dropping ``len % batch_size`` samples per

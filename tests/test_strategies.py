@@ -427,40 +427,10 @@ def test_explore_snn_kill_and_resume_identical_front(tiny, tmp_path, monkeypatch
     assert [t["cfg"] for t in resumed.search.trace] == [t["cfg"] for t in full.search.trace]
 
 
-def test_explore_snn_legacy_kwargs_shim_warns_once_and_matches(tiny):
-    from repro.core.flexplorer import explorer as explorer_mod
-    from repro.core.flexplorer.explorer import EvalSpec, SearchSpec, explore_snn
+def test_explore_snn_rejects_unknown_kwargs(tiny):
+    from repro.core.flexplorer.explorer import explore_snn
 
     net, params, ds = tiny
-    cfg = S.AnnealConfig(t_start=1.0, t_min=0.2, alpha=0.5, seed=0)
-    explorer_mod._LEGACY_WARNED = False
-    with pytest.warns(DeprecationWarning, match="migration table"):
-        legacy = explore_snn(
-            net, params, ds, space=_space(), anneal_cfg=cfg, eval_batch=32, population=4
-        )
-    # second legacy call: shim already warned this process
-    import warnings as _w
-
-    with _w.catch_warnings(record=True) as caught:
-        _w.simplefilter("always")
-        explore_snn(net, params, ds, space=_space(), anneal_cfg=cfg, eval_batch=32, population=4)
-    assert not [w for w in caught if issubclass(w.category, DeprecationWarning)]
-
-    modern = explore_snn(
-        net, params, ds,
-        search=SearchSpec(space=_space(), config=cfg, population=4),
-        evaluate=EvalSpec(batch=32),
-    )
-    assert legacy.search.best == modern.search.best
-    assert legacy.search.cache == modern.search.cache
-
-
-def test_explore_snn_rejects_mixed_and_unknown_kwargs(tiny):
-    from repro.core.flexplorer.explorer import SearchSpec, explore_snn
-
-    net, params, ds = tiny
-    with pytest.raises(TypeError, match="both search="):
-        explore_snn(net, params, ds, search=SearchSpec(), space=_space())
     with pytest.raises(TypeError, match="unexpected keyword"):
         explore_snn(net, params, ds, annealing_config=None)
 
@@ -506,5 +476,3 @@ def test_exploration_result_to_json(tiny):
     assert out["strategy"] == "anneal"
     assert out["weights"]["c_bw"] == 0.0
     assert out["explored_front"]
-    # the legacy result alias still reads
-    assert res.anneal is res.search

@@ -12,8 +12,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.flexplorer import annealer as annealer_lib
 from repro.core.flexplorer import cost as cost_lib
+from repro.core.flexplorer import strategies as strategies_lib
 from repro.core.flexplorer.explorer import SearchSpec, SNNSearchSpace, explore_snn
 from repro.core.network import NetworkConfig, quantize_params
 from repro.core.snn_layer import LayerConfig
@@ -54,7 +54,7 @@ def test_flexplorer_dse_returns_valid_config(trained_mnist):
         test,
         search=SearchSpec(
             space=SNNSearchSpace(ff_bits=(4, 6, 8), leak_bits=(3, 8)),
-            config=annealer_lib.AnnealConfig(t_start=0.5, t_min=0.05, alpha=0.5, eval_divisor=3, seed=1),
+            config=strategies_lib.AnnealConfig(t_start=0.5, t_min=0.05, alpha=0.5, eval_divisor=3, seed=1),
         ),
     )
     report = res.report()
@@ -63,7 +63,7 @@ def test_flexplorer_dse_returns_valid_config(trained_mnist):
     assert report["evaluations"] <= 6  # space size bounds the cache
     assert report["bram"] >= 1
     # every probed candidate recorded for the Fig.-11 style plot
-    assert len(res.anneal.trace) == report["evaluations"]
+    assert len(res.search.trace) == report["evaluations"]
 
 
 def test_annealer_finds_global_optimum_on_known_surface():
@@ -71,9 +71,13 @@ def test_annealer_finds_global_optimum_on_known_surface():
     target = (3, 20)
     hw = lambda cfg: 0.05 * abs(cfg[0] - target[0])
     acc = lambda cfg: 1.0 - 0.1 * abs(cfg[1] - target[1]) / 10.0
-    res = annealer_lib.simulated_annealing(
-        knobs, hw, acc, lambda a: 0.5 * (1 - a),
-        annealer_lib.AnnealConfig(t_start=1.0, t_min=1e-3, alpha=0.7, eval_divisor=1, seed=0),
+    cfg = strategies_lib.AnnealConfig(t_start=1.0, t_min=1e-3, alpha=0.7, eval_divisor=1, seed=0)
+    res = strategies_lib.run_search(
+        strategies_lib.AnnealStrategy(knobs, cfg),
+        knobs,
+        hw,
+        batch_acc_fn=lambda batch: [float(acc(c)) for c in batch],
+        acc_cost_fn=lambda a: 0.5 * (1 - a),
     )
     assert res.best == target
 
