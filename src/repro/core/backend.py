@@ -953,10 +953,11 @@ def _run_int_dynamic(net, qparams, beta_regs, alpha_regs, spikes_in):
         x = s_t
         emitted = []
         for i, (cfg, p, st) in enumerate(zip(net.layers, qparams, states)):
-            st, x = int_layer_step_dynamic(cfg, p, st, x, beta_regs[i], alpha_regs[i])
+            st, spk = int_layer_step_dynamic(cfg, p, st, x, beta_regs[i], alpha_regs[i])
             new_states.append(st)
-            emitted.append(jnp.sum(x, axis=-1))
-        return new_states, (x, jnp.stack(emitted, axis=0))
+            emitted.append(jnp.sum(spk, axis=-1))
+            x = spk.astype(jnp.int8)  # {0, 1}: the next product takes the int8 planes
+        return new_states, (spk, jnp.stack(emitted, axis=0))
 
     _, (out_spikes, emitted) = jax.lax.scan(one_step, states, spikes_in)
     return jnp.sum(out_spikes, axis=0), emitted  # [batch, n_classes], [T, L, batch]
@@ -973,8 +974,22 @@ def run_int_population(
     per-candidate emitted event totals [P, T, n_layers, batch] (each
     candidate quantizes differently, so its event traffic -- and therefore
     its modeled latency/energy -- differs too).
+
+    Every feed-forward product takes ``lowering.population_currents``.
+    Deeper layers integrate {0, 1} spikes, cast to int8, so they always take
+    the int8 planes; the first layer takes them where ``spikes_in`` is int8
+    (``eval_int_population`` casts a set that fits).  The candidates share
+    the input, so under the vmap each plane of the first layer is one
+    ``[batch, n_in] x [n_in, P * n_out]`` product per step.
     """
-    spikes_in = spikes_in.astype(jnp.int32)
+    spikes_in = jnp.asarray(spikes_in)
+    # each layer's weights in the form its input's dtype takes, made once for every step
+    feeds = [spikes_in.dtype] + [jnp.int8] * (len(net.layers) - 1)
+    with jax.named_scope(FF_SCOPE):
+        stacked_qparams = [
+            p._replace(w_ff=lowering.population_weights(p.w_ff, dtype))
+            for p, dtype in zip(stacked_qparams, feeds)
+        ]
 
     def one(qp, beta, alpha):
         return _run_int_dynamic(net, qp, beta, alpha, spikes_in)

@@ -2,7 +2,7 @@
 
 Every feed-forward integration in the simulator is an integer product
 ``spikes @ w_q`` that must come out bit-identical to the int32 reference.
-Four lowerings compute it, and this module is the one place that decides
+Five lowerings compute it, and this module is the one place that decides
 between them and the one place that asks which platform the code runs on:
 
 ``"pallas-int8"``
@@ -12,6 +12,21 @@ between them and the one place that asks which platform the code runs on:
 ``"xla-int32"``
     XLA's integer dot.  Exact for every input; the fallback for wide
     weights and graded inputs.
+``"int8-planes"``
+    The population sweep's product (:func:`population_currents`): the weights of
+    up to 16 bits (every width ``LayerConfig`` allows) split into two int8
+    digit planes, ``hi = w >> 8`` and ``lo = (w & 0xFF) - 128``, each an int8
+    x int8 -> int32 product on the MXU, recombined in int32 by the offset
+    identity ``x @ w = 256 (x @ hi) + x @ lo + 128 sum_i x_i``.  Exact
+    whenever the inputs fit int8 (spike values <= 127; binary spikes always
+    qualify), and over any fan-in below 2**17: then neither plane's product
+    can overflow, and the recombination is int32 arithmetic, which wraps
+    modulo 2**32 exactly as the reference's int32 dot does, so the two
+    agree bit for bit, overflow included.  A population's candidates differ
+    in ``w_bits`` at run time, so every candidate takes two planes, the
+    count that covers every width.  :func:`population_feed` chooses
+    between it and the int32 dot from the input's dtype alone: int8 inputs,
+    which a caller casts only after :func:`fits_int8` held, take the planes.
 ``"pallas-sparse"``
     The ``sparse_accum`` Pallas kernel: an event list scattered into int32
     accumulators.  Exact for every input; the event strategy's TPU path.
@@ -29,6 +44,8 @@ on a TPU.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -40,9 +57,16 @@ __all__ = [
     "PALLAS_SPARSE",
     "F32",
     "INT8_OR_INT32",
+    "INT8_PLANES",
     "on_tpu",
     "interpret",
     "mxu_feed",
+    "WeightPlanes",
+    "population_feed",
+    "weight_planes",
+    "population_weights",
+    "population_currents",
+    "fits_int8",
     "f32_exact",
     "f32_max_input",
     "f32_currents",
@@ -55,6 +79,7 @@ F32 = "f32"
 #: ``mxu_feed`` for a traced input of unknown magnitude: the program holds
 #: both lowerings and picks per call from the batch's maximum (``lax.cond``).
 INT8_OR_INT32 = "pallas-int8|xla-int32"
+INT8_PLANES = "int8-planes"
 
 _INT8_MAX = 127
 _INT8_W_BITS = 8  # weights clip to [int_min, int_max] of w_bits: int8 holds w_bits <= 8
@@ -98,6 +123,71 @@ def mxu_feed(w_bits: int, max_val: int | None) -> str:
     if max_val is None:
         return INT8_OR_INT32
     return PALLAS_INT8 if max_val <= _INT8_MAX else XLA_INT32
+
+
+def population_feed(dtype) -> str:
+    """The lowering :func:`population_currents` takes for inputs of ``dtype``.
+
+    int8 inputs take the int8 planes; a caller casts its spikes to int8 only
+    where :func:`fits_int8` held, so the dtype carries that proof into the
+    traced program.  Any other dtype takes XLA's int32 dot.
+    """
+    return INT8_PLANES if dtype == jnp.int8 else XLA_INT32
+
+
+class WeightPlanes(NamedTuple):
+    """Weights of up to 16 bits as their two int8 digit planes."""
+
+    hi: jax.Array  # w >> 8
+    lo: jax.Array  # (w & 0xFF) - 128
+
+
+def weight_planes(w_q) -> WeightPlanes:
+    """``w_q``'s two int8 digit planes, each in [-128, 127] for ``|w_q| < 2**15``."""
+    return WeightPlanes((w_q >> 8).astype(jnp.int8), ((w_q & 0xFF) - 128).astype(jnp.int8))
+
+
+def population_weights(w_q, dtype):
+    """``w_q`` in the form :func:`population_currents` multiplies inputs of ``dtype`` by.
+
+    For the int8 planes (:func:`population_feed`) the weights split into
+    their planes here, once per sweep: an optimization barrier keeps XLA
+    from sinking the split into the step loop, where it would read the
+    int32 weights and write both planes again in every step.  Any other
+    feed takes ``w_q`` as it is.
+    """
+    if population_feed(dtype) != INT8_PLANES:
+        return w_q
+    return WeightPlanes(*jax.lax.optimization_barrier(tuple(weight_planes(w_q))))
+
+
+def population_currents(x, w):
+    """The population sweep's feed-forward product ``x @ w`` as int32.
+
+    ``w`` is :func:`population_weights`' form for ``x``'s dtype; either
+    lowering is bit-identical to the int32 dot.  Under a vmap over
+    candidates' weights with ``x`` shared, each product is one ``[rows,
+    n_in] x [n_in, P * N]`` dot.
+    """
+    if isinstance(w, WeightPlanes):
+        return _plane_product(x, w)
+    return jnp.einsum("bi,io->bo", x.astype(jnp.int32), w)
+
+
+def fits_int8(x):
+    """Traced: whether every value of ``x`` lies in int8's range."""
+    fits = jnp.max(x, initial=0) <= _INT8_MAX
+    if not jnp.issubdtype(x.dtype, jnp.unsignedinteger):
+        fits &= jnp.min(x, initial=0) >= -_INT8_MAX - 1
+    return fits
+
+
+def _plane_product(x, planes: WeightPlanes):
+    x8 = x.astype(jnp.int8)
+    rows = jnp.sum(x8, axis=-1, keepdims=True, dtype=jnp.int32)
+    hi_x = jnp.matmul(x8, planes.hi, preferred_element_type=jnp.int32)
+    lo_x = jnp.matmul(x8, planes.lo, preferred_element_type=jnp.int32)
+    return 256 * hi_x + lo_x + 128 * rows
 
 
 def f32_exact(w_bits: int, max_val: int, rows: int) -> bool:

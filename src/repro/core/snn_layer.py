@@ -56,7 +56,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import coeff_gen
+from repro.core import coeff_gen, lowering
 from repro.core.coeff_gen import DecayCode
 from repro.core.fixed_point import saturate
 
@@ -335,8 +335,13 @@ def int_layer_step_dynamic(
     values, so a vmap over candidates (whose ``leak_bits`` differ) compiles to
     one program.  ``beta_register`` / ``alpha_register`` are packed 9-bit
     ``DecayCode.decay_rate_register`` values.
+
+    The feed-forward product takes ``lowering.population_currents``, which
+    chooses its lowering (int8 digit planes on the MXU, or the int32 dot).
     """
-    u, i_syn = int_phase_a(cfg, params, state, s_in)
+    with jax.named_scope(FF_SCOPE):
+        ff_acc = lowering.population_currents(s_in, params.w_ff)
+    u, i_syn = _integrate_acc(cfg, params, state, ff_acc)
     return int_phase_b(
         cfg,
         params,

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backend as backend_lib
+from repro.core import lowering
 from repro.core import shard as shard_lib
 from repro.core.network import NetworkConfig, init_float_params, run_float, run_int
 from repro.core.snn_layer import Topology
@@ -274,16 +275,22 @@ def _population_fwd(net, stacked_qparams, beta_regs, alpha_regs, spikes):
     )
 
 
-@functools.partial(jax.jit, static_argnums=1)
-def _batch_windows(spikes, size):
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _batch_windows(spikes, size, int8):
     """Placed ``[N, T, C]`` rasters cut into time-major ``[T, n, C]`` batches of ``size``.
 
-    The last batch holds what is left (``N % size`` samples, if any). Also
-    returns each batch's mean input events per step ``[T]``, so that no
-    launch needs a reduction of its own for them.
+    The last batch holds what is left (``N % size`` samples, if any). With
+    ``int8`` each batch is cast to int8, the dtype that takes the population
+    sweep's int8 planes (``lowering.population_feed``). Also returns each
+    batch's mean input events per step ``[T]``, so that no launch needs a
+    reduction of its own for them.
     """
     batches = [jnp.swapaxes(spikes[i : i + size], 0, 1) for i in range(0, spikes.shape[0], size)]
-    return batches, [jnp.mean(jnp.sum(b != 0, axis=-1), axis=-1) for b in batches]
+    in_ev = [jnp.mean(jnp.sum(b != 0, axis=-1), axis=-1) for b in batches]
+    return [b.astype(jnp.int8) for b in batches] if int8 else batches, in_ev
+
+
+_fits_int8 = jax.jit(lowering.fits_int8)
 
 
 def eval_int_population(
@@ -323,9 +330,12 @@ def eval_int_population(
     ``[N, T, C]`` layout (on a mesh, whole on every device), and cuts it
     there into time-major batches in one program, with each batch's input
     events; nothing is gathered or transposed on the host, and a batch's
-    launch moves no spikes.  The set takes N x T x C bytes of each device
-    for the call, twice while it is cut: 71.7 MB for 1,024 rasters of 100
-    steps over 700 channels in uint8.
+    launch moves no spikes.  Before the cut, one reduction on the device
+    tells the host whether the whole set fits int8 (``lowering.fits_int8``):
+    then the batches are cut as int8 and every product of the sweep takes
+    the int8 planes, else the first layer's takes the int32 dot.  The set
+    takes N x T x C bytes of each device for the call, twice while it is
+    cut: 71.7 MB for 1,024 rasters of 100 steps over 700 channels in uint8.
 
     Profiler spans: ``neura.dse.stack`` (arguments ``candidates``;
     ``cores``, the network's physical cores after the split of wide layers;
@@ -333,8 +343,10 @@ def eval_int_population(
     ``shards``, the devices the population was placed over) around building
     the population; ``neura.dse.place`` (``bytes`` placed on each device,
     ``samples``) around placing the rasters, until they are on the device,
-    and launching their cut into batches; then one ``neura.dse.batch`` per
-    data batch (``index``, ``samples``, ``steps``) holding its
+    their range test and launching their cut into batches; then one
+    ``neura.dse.batch`` per data batch (``index``, ``samples``, ``steps``;
+    ``ff_feed``, the lowering the batch's first product takes:
+    ``lowering.INT8_PLANES`` or ``lowering.XLA_INT32``) holding its
     ``neura.dse.launch`` and ``neura.dse.readback``; the rest of a batch is
     the host reduction.
     """
@@ -366,9 +378,11 @@ def eval_int_population(
     with jax.profiler.TraceAnnotation(
         "neura.dse.place", bytes=int(ds.spikes.nbytes), samples=n_samples
     ):
-        placed = shard_lib.place_replicated(ds.spikes, dmesh).block_until_ready()
-        windows, window_in_ev = _batch_windows(placed, size)
+        placed = shard_lib.place_replicated(ds.spikes, dmesh)
+        int8 = bool(_fits_int8(placed))  # waits for the placement, then one reduction
+        windows, window_in_ev = _batch_windows(placed, size, int8)
         del placed  # freed once cut: the windows hold the set
+    ff_feed = lowering.population_feed(windows[0].dtype) if windows else None
 
     correct = np.zeros(P, np.int64)
     total = 0
@@ -377,7 +391,9 @@ def eval_int_population(
     for index, spikes in enumerate(windows):
         labels = ds.labels[index * size : (index + 1) * size]
         n = len(labels)
-        with jax.profiler.TraceAnnotation("neura.dse.batch", index=index, samples=n, steps=steps):
+        with jax.profiler.TraceAnnotation(
+            "neura.dse.batch", index=index, samples=n, steps=steps, ff_feed=ff_feed
+        ):
             with jax.profiler.TraceAnnotation("neura.dse.launch"):
                 out = pop_fwd(spikes)
             with jax.profiler.TraceAnnotation("neura.dse.readback"):

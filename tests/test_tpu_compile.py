@@ -156,6 +156,75 @@ def test_f32_feed_forward_lowers_to_one_bf16_pass(one_chip):
     assert not any("operand_precision={highest" in l for l in dots)
 
 
+def test_population_sweep_feeds_the_mxu_int8_planes(one_chip):
+    """The population program at ``shd-dse-sweep``'s size (32 candidates,
+    batch 256, T=100, 700-200-20 Synaptic, ATA-T) on int8 rasters: every
+    feed-forward product multiplies int8 operands under ``neura.core.ff``,
+    the weights split into their planes once, before the step loop, and the
+    planes' recombination (``256 (x @ hi) + x @ lo + 128 sum x``) stays in
+    ff-scoped fusions, while the ATA-T product stays an int32 one under
+    ``neura.core.recurrent``, in fusions that hold none of the recombination."""
+    import re
+
+    from repro.core.network import NetworkConfig
+    from repro.core.snn_layer import IntLayerParams, LayerConfig, NeuronModel, ResetMode, Topology
+    from repro.snn.train import _population_fwd
+
+    syn = dict(neuron=NeuronModel.SYNAPTIC, reset=ResetMode.ZERO, w_bits=8, w_rec_bits=8)
+    net = NetworkConfig(
+        (LayerConfig(700, 200, topology=Topology.ATA_T, **syn), LayerConfig(200, 20, **syn)), 100
+    )
+    P, batch = 32, 256
+
+    def put(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stacked = [
+        IntLayerParams(put((P, 700, 200)), put((P, 200, 200)), put((P,))),
+        IntLayerParams(put((P, 200, 20)), put((P, 0)), put((P,))),
+    ]
+    regs = put((P, 2))
+    txt = _population_fwd.lower(net, stacked, regs, regs, put((100, batch, 700), jnp.int8))
+    txt = txt.compile().as_text()
+    assert " conditional(" not in txt
+
+    comps, name = {}, None
+    for line in txt.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name:
+            comps[name].append(line)
+
+    def inner(comp):
+        """Every line of ``comp`` and of the computations it calls."""
+        lines = list(comps.get(comp, []))
+        for line in comps.get(comp, []):
+            for callee in re.findall(r"calls=%([\w.\-]+)", line):
+                lines += inner(callee)
+        return lines
+
+    entry = next(c for c in comps if c.startswith("main"))
+    split = [l for l in comps[entry] if re.search(r"s8\[32,700,200\].* fusion\(", l)]
+    assert split and all("neura.core.ff" in l for l in split)  # the planes, once per call
+
+    types = dict(re.findall(r"%(\S+) = (\w+)\[", txt))
+    conv = r"= \w+\[[^\]]*\]\S* convolution\(%(\S+), %(\S+)\).*?op_name=\"([^\"]*)\""
+    convs = re.findall(conv, txt)
+    int8 = [name for a, b, name in convs if types[a] == types[b] == "s8"]
+    assert len(int8) == 4 and all("neura.core.ff" in n for n in int8)  # two planes, two layers
+    rec = [(a, b) for a, b, name in convs if "neura.core.recurrent" in name]
+    assert rec and all(types[a] == types[b] == "s32" for a, b in rec)
+
+    fusions = [l for c in comps for l in comps[c] if " fusion(" in l]
+    recurrent = [l for l in fusions if "/neura.core.recurrent/" in l]
+    assert recurrent
+    for line in recurrent:
+        body = " ".join(inner(re.search(r"calls=%([\w.\-]+)", line).group(1)))
+        assert "constant(128)" not in body and "constant(256)" not in body
+
+
 # ---------------------------------------------------------------------------
 # TPU rules, held on CPU by steering the platform
 # ---------------------------------------------------------------------------
